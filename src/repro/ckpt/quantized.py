@@ -122,7 +122,29 @@ def tree_bytes(tree) -> int:
 # -- packed single-file checkpoints (launch/serve --save/--load-quantized) ---
 
 PACKED_FORMAT = "comq-packed-qt"
-PACKED_VERSION = 1
+# 2: planar bit-field layout (quantizer.pack_int4/pack_int2 — field f of
+# a byte holds the f-th slice of the last dim). Version-1 and headerless
+# files packed adjacent codes into one byte; their packed leaves would
+# decode to the wrong codes, so they are refused (re-quantize instead).
+PACKED_VERSION = 2
+
+
+def _has_packed_leaf(tree) -> bool:
+    if is_qtensor(tree):
+        return bool(tree.get("packed_cpb") or tree.get("packed4"))
+    if isinstance(tree, dict):
+        return any(_has_packed_leaf(v) for v in tree.values())
+    if isinstance(tree, (list, tuple)):
+        return any(_has_packed_leaf(v) for v in tree)
+    return False
+
+
+def _refuse_old_layout(path: str, blob, what: str) -> None:
+    if _has_packed_leaf(blob.get("tree")):
+        raise PackedCkptError(
+            f"{path}: {what} holds codes in the interleaved pre-version-"
+            f"{PACKED_VERSION} packing, which this reader would decode "
+            "wrong — re-quantize to write the planar layout")
 
 
 class PackedCkptError(RuntimeError):
@@ -185,6 +207,7 @@ def load_packed_ckpt(path: str, expect_crc: Optional[int] = None
             raise PackedCkptError(
                 f"{path}: legacy headerless checkpoint has no checksum "
                 f"to match the expected {expect_crc:#010x}")
+        _refuse_old_layout(path, blob, "legacy headerless checkpoint")
         warnings.warn(f"{path}: legacy headerless packed checkpoint — "
                       "no checksum to verify; re-save to upgrade",
                       stacklevel=2)
@@ -207,4 +230,7 @@ def load_packed_ckpt(path: str, expect_crc: Optional[int] = None
             f"{path}: checksum {crc:#010x} does not match the journaled "
             f"{int(expect_crc):#010x} — the spill was replaced or the "
             "journal belongs to a different run")
-    return pickle.loads(payload)
+    out = pickle.loads(payload)
+    if blob["version"] < PACKED_VERSION:
+        _refuse_old_layout(path, out, f"version-{blob['version']} file")
+    return out

@@ -45,6 +45,14 @@ from repro.core.quantizer import (EPS, QuantSpec, init_per_channel,
 
 Array = jax.Array
 
+# Every product of the solve runs at full f32 precision. A TPU multiplies
+# f32 operands as one bf16 pass by default, which rounds H, W and the
+# maintained P = H·R to 8 mantissa bits: on a TPU v5e that changed 4.3 %
+# of h2o-danube-1.8b's layer-0 w_down codes and 0.7 % of its wq codes
+# (DESIGN.md §3). The Grams need no such setting — their bf16-valued taps
+# multiply exactly. On the CPU this is the default anyway.
+_mm = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
 
 def gram(x: Array) -> Array:
     x = x.astype(jnp.float32)
@@ -54,17 +62,17 @@ def gram(x: Array) -> Array:
 def _h_error(h: Array, w: Array, wq: Array) -> Array:
     """‖X(W − W_q)‖ from H: sqrt(tr(RᵀHR))."""
     r = w - wq
-    val = jnp.sum(r * (h @ r))
+    val = jnp.sum(r * _mm(h, r))
     return jnp.sqrt(jnp.maximum(val, 0.0))
 
 
 def _delta_update_h(h: Array, w: Array, qf: Array, per_layer: bool) -> Array:
-    hq = h @ qf
+    hq = _mm(h, qf)
     if per_layer:
-        num = jnp.sum(qf * (h @ w))
+        num = jnp.sum(qf * _mm(h, w))
         den = jnp.sum(qf * hq)
         return jnp.where(den > EPS, num / den, 1.0)
-    num = jnp.sum(qf * (h @ w), axis=0)
+    num = jnp.sum(qf * _mm(h, w), axis=0)
     den = jnp.sum(qf * hq, axis=0)
     return jnp.where(den > EPS, num / den, 1.0)
 
@@ -113,7 +121,7 @@ def _comq_h_core(h: Array, w: Array, *, spec: QuantSpec):
     errs = [_h_error(h, w, qf * delta)]
 
     for _ in range(spec.sweeps):
-        p = h @ (w - qf * delta)                          # H·R
+        p = _mm(h, w - qf * delta)                        # H·R
         p, qf = _sweep_h(h, p, qf, delta, z_lo, z_hi, orders, hdiag)
         delta = _delta_update_h(h, w, qf, per_layer)
         errs.append(_h_error(h, w, qf * delta))
@@ -176,7 +184,7 @@ def panel_sweep_dq_ref(h_bb: Array, s0: Array, qf_b: Array, delta: Array,
         qf_b, du = carry
         qg = qf_b[t]
         hg = hdiag_b[t]
-        st = s0[t] - h_bb[t, :] @ du          # rows ≥ t of du are still 0
+        st = s0[t] - _mm(h_bb[t, :], du)      # rows ≥ t of du are still 0
         denom = delta * hg
         ratio = st / jnp.where(denom > 0, denom, 1.0)
         q_new = jnp.clip(jnp.round(ratio + qg),
@@ -221,8 +229,8 @@ def _blocked_core(hp: Array, wp: Array, hdiag: Array, delta, z_lo, z_hi, *,
     qf = wp / delta
 
     if schedule == "trailing":
-        hw = hp @ wp                                       # H·W, once
-        p = hp @ (wp - qf * delta)                         # P⁰ = H·R⁰
+        hw = _mm(hp, wp)                                   # H·W, once
+        p = _mm(hp, wp - qf * delta)                       # P⁰ = H·R⁰
 
         def h_err(p, qf, delta):
             # ‖XR‖ = sqrt(tr(RᵀHR)) = sqrt(Σ R⊙P); padded rows of H are
@@ -241,7 +249,7 @@ def _blocked_core(hp: Array, wp: Array, hdiag: Array, delta, z_lo, z_hi, *,
                 hd_b = jax.lax.dynamic_slice(hdiag, (b * B,), (B,))
                 qf_b, dq = _panel_and_dq(panel_fn, h_bb, s0, qf_b, delta,
                                          z_lo, z_hi, hd_b)
-                p = p - h_cols @ dq                        # rank-B trailing
+                p = p - _mm(h_cols, dq)                    # rank-B trailing
                 qf = jax.lax.dynamic_update_slice(qf, qf_b, (b * B, 0))
                 return p, qf
 
@@ -264,7 +272,7 @@ def _blocked_core(hp: Array, wp: Array, hdiag: Array, delta, z_lo, z_hi, *,
             def body(b, qf):
                 r = wp - qf * delta
                 h_rows = jax.lax.dynamic_slice(hp, (b * B, 0), (B, m_pad))
-                s0 = h_rows @ r                            # (B, n) MXU
+                s0 = _mm(h_rows, r)                        # (B, n) MXU
                 h_bb = jax.lax.dynamic_slice(h_rows, (0, b * B), (B, B))
                 qf_b = jax.lax.dynamic_slice(qf, (b * B, 0), (B, n))
                 hd_b = jax.lax.dynamic_slice(hdiag, (b * B,), (B,))

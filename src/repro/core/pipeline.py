@@ -133,11 +133,11 @@ def dequant_qtensor(t: dict, dtype=jnp.float32) -> Array:
     return w2d.reshape(t["shape"]).astype(dtype)
 
 
-def dequantize_tree(tree):
+def dequantize_tree(tree, dtype=jnp.float32):
     """Replace every QTensor leaf with its dequantized dense weight."""
     def walk(node):
         if is_qtensor(node):
-            return dequant_qtensor(node)
+            return dequant_qtensor(node, dtype)
         if isinstance(node, dict):
             return {k: walk(v) for k, v in node.items()}
         if isinstance(node, (list, tuple)):
@@ -269,7 +269,8 @@ def _col_err2(h: Array, w: Array, wq: Array) -> Array:
     """Per-column squared reconstruction error Σ_i R⊙(HR): lets one fused
     H·R matmul attribute exact per-leaf errors after a concatenated solve."""
     r = w - wq
-    return jnp.sum(r * (h @ r), axis=0)
+    return jnp.sum(r * jnp.matmul(h, r, precision=jax.lax.Precision.HIGHEST),
+                   axis=0)
 
 
 def _norm_of(e2_slice: Array) -> Array:
@@ -1249,8 +1250,11 @@ def _quantize_vlm(params, cfg, plan, x, resolve, method, vision_embeds,
 # materialize a runnable dequantized model
 # ---------------------------------------------------------------------------
 
-def materialize(qparams, cfg) -> Any:
-    """Fold the __qlayers__ side table back into stacked dense params."""
+def materialize(qparams, cfg, dtype=jnp.float32) -> Any:
+    """Fold the __qlayers__ side table back into stacked dense params
+    (quantized leaves dequantized to `dtype`; the model computes in its
+    compute dtype either way, so bf16 halves the footprint and changes no
+    result)."""
     params = {k: v for k, v in qparams.items() if k != "__qlayers__"}
     table = qparams.get("__qlayers__", {})
     if not table:
@@ -1263,11 +1267,11 @@ def materialize(qparams, cfg) -> Any:
             cross_p = params["groups"]["cross"]
             for gi in range(g):
                 for si in range(spg):
-                    deq = dequantize_tree(table[f"self_{gi}_{si}"])
+                    deq = dequantize_tree(table[f"self_{gi}_{si}"], dtype)
                     self_p = jax.tree_util.tree_map(
                         lambda a, s: a.at[gi, si].set(s.astype(a.dtype)),
                         self_p, deq)
-                deq = dequantize_tree(table[f"cross_{gi}"])
+                deq = dequantize_tree(table[f"cross_{gi}"], dtype)
                 cross_p = jax.tree_util.tree_map(
                     lambda a, s: a.at[gi].set(s.astype(a.dtype)),
                     cross_p, deq)
@@ -1277,14 +1281,15 @@ def materialize(qparams, cfg) -> Any:
             self_rows = [
                 jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs),
-                    *[dequantize_tree(table[f"self_{gi}_{si}"])
+                    *[dequantize_tree(table[f"self_{gi}_{si}"], dtype)
                       for si in range(spg)])
                 for gi in range(g)]
             self_p = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
                                             *self_rows)
             cross_p = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs),
-                *[dequantize_tree(table[f"cross_{gi}"]) for gi in range(g)])
+                *[dequantize_tree(table[f"cross_{gi}"], dtype)
+                  for gi in range(g)])
         params = dict(params)
         params["groups"] = {"self": self_p, "cross": cross_p}
         return params
@@ -1292,16 +1297,17 @@ def materialize(qparams, cfg) -> Any:
         layers = params["layers"]
         for key, lp_q in table.items():
             l = int(key)
-            deq = dequantize_tree(lp_q)
+            deq = dequantize_tree(lp_q, dtype)
             layers = jax.tree_util.tree_map(
                 lambda a, s: a.at[l].set(s.astype(a.dtype)), layers, deq)
     else:
         # stripped checkpoint (ckpt.strip_for_serving): rebuild the stack
         # from the table (it carries every per-layer leaf, dense included)
-        per = [dequantize_tree(table[k]) for k in sorted(table, key=int)]
+        per = [dequantize_tree(table[k], dtype)
+               for k in sorted(table, key=int)]
         layers = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per)
     params = dict(params)
     params["layers"] = layers
     if is_qtensor(params.get("unembed", None)):
-        params["unembed"] = dequant_qtensor(params["unembed"])
+        params["unembed"] = dequant_qtensor(params["unembed"], dtype)
     return params

@@ -75,33 +75,47 @@ def from_unsigned(u: Array, z_lo: Array) -> Array:
     return u.astype(jnp.int32) + z_lo
 
 
+def _pack_planes(u: Array, cpb: int) -> Array:
+    """Planar packing along the last dim: bit field f (lowest first) of
+    byte c holds code f·(n/cpb) + c. Unpacking is shifts, masks and one
+    concatenation — no interleave — so a tile of packed bytes widens into
+    `cpb` tile-aligned planes inside a TPU kernel (kernels/quant_matmul,
+    kernels/paged_attention)."""
+    n = u.shape[-1]
+    assert n % cpb == 0, f"planar packing needs last dim % {cpb} == 0"
+    w, p = 8 // cpb, n // cpb
+    out = u[..., :p].astype(jnp.uint8)
+    for f in range(1, cpb):
+        out = out | (u[..., f * p:(f + 1) * p].astype(jnp.uint8) << (w * f))
+    return out
+
+
+def _unpack_planes(b: Array, cpb: int) -> Array:
+    w, mask = 8 // cpb, jnp.uint8((1 << (8 // cpb)) - 1)
+    return jnp.concatenate([(b >> (w * f)) & mask for f in range(cpb)],
+                           axis=-1)
+
+
 def pack_int4(u: Array) -> Array:
-    """Pack uint4 codes (last dim even) into uint8 pairs: low nibble first."""
-    assert u.shape[-1] % 2 == 0, "pack_int4 needs even last dim"
-    lo = u[..., 0::2].astype(jnp.uint8)
-    hi = u[..., 1::2].astype(jnp.uint8)
-    return lo | (hi << 4)
+    """Pack uint4 codes (last dim even) two per byte, planar: the low
+    nibble holds the first half of the last dim, the high nibble the
+    second half."""
+    return _pack_planes(u, 2)
 
 
 def unpack_int4(b: Array) -> Array:
-    lo = b & jnp.uint8(0x0F)
-    hi = (b >> 4) & jnp.uint8(0x0F)
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(*b.shape[:-1], b.shape[-1] * 2)
+    return _unpack_planes(b, 2)
 
 
 def pack_int2(u: Array) -> Array:
-    """Pack uint2 codes (last dim % 4 == 0) four per byte, lowest bits
-    first — the 0.25 B/param storage of a 2-bit policy leaf."""
-    assert u.shape[-1] % 4 == 0, "pack_int2 needs last dim % 4 == 0"
-    parts = [u[..., i::4].astype(jnp.uint8) << (2 * i) for i in range(4)]
-    return parts[0] | parts[1] | parts[2] | parts[3]
+    """Pack uint2 codes (last dim % 4 == 0) four per byte, planar (field
+    f holds the f-th quarter of the last dim) — the 0.25 B/param storage
+    of a 2-bit policy leaf."""
+    return _pack_planes(u, 4)
 
 
 def unpack_int2(b: Array) -> Array:
-    parts = [(b >> (2 * i)) & jnp.uint8(0x03) for i in range(4)]
-    out = jnp.stack(parts, axis=-1)
-    return out.reshape(*b.shape[:-1], b.shape[-1] * 4)
+    return _unpack_planes(b, 4)
 
 
 def codes_per_byte(bits: int) -> int:

@@ -30,7 +30,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist.collectives import psum_gram
@@ -96,8 +95,9 @@ def _gram_fn(mesh: Mesh):
     """Jitted shard_map'd Gram, cached per mesh (and per shape via jit):
     the calibration walk calls this once per tap per layer — without the
     cache every call would re-trace the shard_map."""
-    return jax.jit(shard_map(lambda t: psum_gram(t, "data"), mesh=mesh,
-                             in_specs=P("data"), out_specs=P()))
+    return jax.jit(jax.shard_map(lambda t: psum_gram(t, "data"), mesh=mesh,
+                                 in_specs=P("data"), out_specs=P(),
+                                 check_vma=False))
 
 
 @functools.lru_cache(maxsize=8)
@@ -105,8 +105,9 @@ def _batched_gram_fn(mesh: Mesh):
     def local(t):
         t = t.astype(jnp.float32)
         return jax.lax.psum(jnp.einsum("ecd,ecf->edf", t, t), "data")
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=P(None, "data"),
-                             out_specs=P()))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=P(None, "data"), out_specs=P(),
+                                 check_vma=False))
 
 
 def sharded_gram(mesh: Mesh, tap: Array) -> Array:
@@ -189,12 +190,12 @@ def _solve_fn(mesh: Mesh, spec, method: str, block: int):
         return r.q, r.delta, r.z_lo, e2_before, e2_after
 
     s = solver_specs(mesh)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(s["h"], s["w"], s["perm"]),
         out_specs=(s["q"], s["delta"], s["z"], s["col_err2"],
                    s["col_err2"]),
-        check_rep=False))
+        check_vma=False))
 
 
 def sharded_solve(mesh: Mesh, h: Array, w2d: Array, spec, method: str,

@@ -1,15 +1,15 @@
 """jit'd public wrappers around the Pallas kernels with backend dispatch.
 
-On TPU the real kernels run; on CPU (this container) `interpret=True`
-executes the kernel body for correctness tests, and the `xla` mode uses the
-pure-jnp oracle (what the dry-run lowers — Pallas does not lower to the
-host platform). Mode resolution: explicit arg > REPRO_KERNEL_MODE env >
-backend default.
+On TPU the real kernels run; `interpret` executes the kernel body on the
+CPU for correctness tests, and the `xla` mode uses the pure-jnp oracle
+(what the dry-run lowers — Pallas does not lower to the host platform).
+Mode resolution: an explicit argument, else the backend default — Pallas
+on TPU, XLA elsewhere. There is no environment override: on a TPU the
+kernels run unless a caller asks for another mode by name.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -28,10 +28,9 @@ Array = jax.Array
 
 def resolve_mode(mode: Optional[str] = None) -> str:
     if mode:
+        if mode not in ("pallas", "interpret", "xla"):
+            raise ValueError(f"unknown kernel mode {mode!r}")
         return mode
-    env = os.environ.get("REPRO_KERNEL_MODE")
-    if env:
-        return env
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 

@@ -1,0 +1,27 @@
+"""JAX persistent compilation cache for the launchers and chip_smoke.py.
+
+`JAX_COMPILATION_CACHE_DIR`, when set, is the cache: JAX reads it itself
+and nothing here overrides it. Otherwise the cache lives at one fixed
+path inside the checkout, `<repo>/.jax_cache` (git-ignored) — the path is
+part of every cache key, so it never depends on a temp name, a pid or
+the time, and a second process of the same checkout hits what the first
+one compiled.
+"""
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+import jax
+
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its directory and
+    return that directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
+    return str(REPO_CACHE_DIR)

@@ -27,17 +27,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt import (CheckpointManager, pack_tree, policy_extra,
-                        save_packed_ckpt, tree_bytes)
+                        save_packed_ckpt, strip_for_serving, tree_bytes)
 from repro.configs import get_config, get_smoke_config
-from repro.core import (QuantSpec, materialize, parse_policy,
-                        policy_from_budget, quantize_model)
+from repro.core import (QuantSpec, parse_policy, policy_from_budget,
+                        quantize_model, serving_params)
 from repro.ft import (FaultInjector, Heartbeat, QuantJournal,
                       run_with_restarts)
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models import BuildPlan, init_params, lm_loss
 from repro.obs import MetricsRegistry, Tracer, next_trace_path
 
 
-def main():
+def main(argv=None):
+    """Parse `argv` (default: sys.argv), quantize, save, evaluate; prints
+    the JSON summary line and returns it as a dict."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -109,9 +112,10 @@ def main():
                     help="dump the quant.* metrics registry (layers/"
                          "leaves counters, per-leaf error + seconds "
                          "histograms) as metrics.jsonl + metrics.prom")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.restarts and not args.journal:
         raise SystemExit("--restarts needs --journal (resume source)")
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     plan = BuildPlan(remat=False)
@@ -172,6 +176,21 @@ def main():
     elif args.shard_data:
         from repro.dist import data_mesh
         mesh = data_mesh()
+    # quality: eval loss fp vs quantized on a held-out batch. The fp loss
+    # is taken before the solve so the f32 master copy can be dropped
+    # after it: the quantized loss runs on the packed serving tree, and
+    # no two dense f32 copies of the model are ever live at once.
+    ev = jax.random.randint(jax.random.PRNGKey(7),
+                            (args.calib_batch, args.calib_seq), 0,
+                            cfg.vocab_size)
+    batch = {"tokens": ev, "labels": ev}
+    if ve is not None:
+        batch["vision_embeds"] = ve
+    eval_loss = jax.jit(lambda p, b: lm_loss(p, cfg, plan, b)[0])
+    fp_loss = float(eval_loss(params, batch))
+    dense_bytes = sum(l.size * l.dtype.itemsize for l in
+                      jax.tree_util.tree_leaves(params))
+
     injector = FaultInjector.parse(args.inject) if args.inject else None
     # observability (DESIGN.md §10): absent flags keep the pipeline on the
     # zero-cost null singletons
@@ -215,15 +234,19 @@ def main():
 
         run_with_restarts(attempt, progress, max_restarts=args.restarts,
                           exceptions=(RuntimeError,), backoff_s=0.0)
-        qparams, report = box["out"]
+        qparams, report = box.pop("out")
     else:
         qparams, report = run_once(args.resume)
     dt = time.time() - t0
 
-    # quantized checkpoint (each QTensor packed to its own bit width) +
-    # the policy metadata that produced it (ckpt.restore_policy reads it);
+    # quantized checkpoint: the stripped serving tree (top-level params +
+    # the __qlayers__ table, each QTensor packed to its own bit width) —
+    # exactly what `serve --load-quantized` reads — plus the policy
+    # metadata that produced it (ckpt.restore_policy reads it);
     # CheckpointManager writes are atomic+fsynced (tmp → rename)
-    packed = pack_tree(qparams["__qlayers__"])
+    served = strip_for_serving(qparams)
+    qparams = params = None          # drop the f32 master copy
+    packed = pack_tree(served)
     mgr = CheckpointManager(args.out_dir, keep=2)
     mgr.save(0, packed, extra=policy_extra(policy=spec, arch=cfg.name,
                                            bits=args.bits))
@@ -236,16 +259,14 @@ def main():
             if isinstance(a, jax.Array) else a, packed)
         save_packed_ckpt(args.save_packed, host, arch=cfg.name,
                          bits=args.bits)
-
-    # quality: eval loss fp vs quantized on a held-out batch
-    ev = jax.random.randint(jax.random.PRNGKey(7),
-                            (args.calib_batch, args.calib_seq), 0,
-                            cfg.vocab_size)
-    batch = {"tokens": ev, "labels": ev}
-    if ve is not None:
-        batch["vision_embeds"] = ve
-    fp_loss = float(lm_loss(params, cfg, plan, batch)[0])
-    q_loss = float(lm_loss(materialize(qparams, cfg), cfg, plan, batch)[0])
+        del host
+    if cfg.family == "vlm":
+        from repro.core import materialize
+        qeval = materialize(served, cfg)
+    else:
+        qeval = serving_params(served, cfg)
+    q_loss = float(eval_loss(qeval, batch))
+    del qeval
 
     if tracer is not None:
         tp = next_trace_path(args.trace, "quantize")
@@ -256,10 +277,8 @@ def main():
         registry.dump_prometheus(os.path.join(args.metrics, "metrics.prom"))
         print(f"# metrics: {args.metrics}/metrics.jsonl + metrics.prom")
 
-    dense_bytes = sum(l.size * l.dtype.itemsize for l in
-                      jax.tree_util.tree_leaves(params))
     from repro.core import QuantPolicy
-    print(json.dumps({
+    summary = {
         "arch": cfg.name, "method": args.method, "bits": args.bits,
         "mixed_policy": (isinstance(spec, QuantPolicy)
                          and not spec.is_uniform()),
@@ -280,7 +299,9 @@ def main():
         "resumed_leaves": report.resumed_leaves,
         "faults_fired": (len(injector.fired) if injector is not None
                          else 0),
-    }))
+    }
+    print(json.dumps(summary))
+    return summary
 
 
 if __name__ == "__main__":

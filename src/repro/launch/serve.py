@@ -47,6 +47,7 @@ from repro.core import (QuantSpec, materialize, quantize_model,
                         serving_params)
 from repro.ft import (FaultInjector, Heartbeat, Journal, SimulatedKill,
                       run_with_restarts)
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models import BuildPlan, count_params, init_params
 from repro.obs import MetricsRegistry, Tracer, next_trace_path
 from repro.serve import (Engine, Runtime, ServeConfig, blocks_for,
@@ -70,7 +71,9 @@ def _quantize(params, cfg, plan, bits: int):
     return qparams
 
 
-def main():
+def main(argv=None):
+    """Parse `argv` (default: sys.argv) and serve; prints the JSON summary
+    line and returns it as a dict."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -142,7 +145,8 @@ def main():
                          "pool gauges, preemption counters) to "
                          "DIR/metrics.jsonl + DIR/metrics.prom "
                          "(paged engine only)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     plan = BuildPlan(remat=False)
@@ -230,14 +234,15 @@ def main():
             np.stack(prompts),
             max_new_tokens=args.max_new, temperature=args.temperature)
         dt = time.time() - t0
-        print(json.dumps({
+        summary = {
             "arch": cfg.name, "engine": "static",
             "requests": args.num_requests, "new_tokens": int(out.size),
             "seconds": round(dt, 2),
             "tok_per_s": round(out.size / dt, 1),
             "sample": out[0, :8].tolist(),
-        }))
-        return
+        }
+        print(json.dumps(summary))
+        return summary
 
     bucket = 1 << max(args.prompt_len - 1, 1).bit_length()
     maxb = blocks_for(bucket + args.max_new, args.block_size)
@@ -347,6 +352,7 @@ def main():
         "admission": args.admission,
         "packed_qt": packed_serve,
         "prompt_lens": [int(r.prompt_len) for r in reqs],
+        "out_tokens": sum(len(r.out_tokens) for r in reqs),
         "ttft_s": [round(t, 4) for t in metrics["ttft_s"]],
         "sample": reqs[0].out_tokens[:8] if reqs else [],
     })
@@ -355,6 +361,7 @@ def main():
     metrics = {k: (round(v, 4) if isinstance(v, float) else v)
                for k, v in metrics.items()}
     print(json.dumps(metrics))
+    return metrics
 
 
 if __name__ == "__main__":

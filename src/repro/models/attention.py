@@ -489,22 +489,35 @@ def paged_gather(pool: Array, block_tables: Array) -> Array:
     return pool.reshape(NB * BS, *pool.shape[2:])[idx.reshape(B, MAXB * BS)]
 
 
+def _check_grouped(q: Array, k_pool: Array) -> None:
+    """The Pallas paged kernels need grouped GQA; a non-grouped head
+    layout is refused, never routed silently to the XLA gather (the
+    paged runtime serves grouped archs only — launch/serve.py sends the
+    rest to the static engine)."""
+    H, KV = q.shape[2], k_pool.shape[2]
+    if H % KV:
+        raise ValueError(
+            f"paged decode kernel needs grouped GQA: q {tuple(q.shape)} has "
+            f"{H} heads over k_pool {tuple(k_pool.shape)} with {KV} kv "
+            "heads; pass mode='xla' explicitly for the gather path")
+
+
 def paged_decode_attend(q: Array, k_pool: Array, v_pool: Array,
                         block_tables: Array, lengths: Array,
                         head_map: Array, *, window: int = 0,
                         mode: Optional[str] = None) -> Array:
     """q: (B, 1, Hp, hd); lengths: (B,) valid tokens per slot (0 inactive).
 
-    Backend dispatch mirrors kernels/ops.py: on TPU (or forced interpret)
-    the Pallas paged kernel DMAs pages via scalar-prefetched block tables;
-    the default XLA path gathers the slot's pages into logical order and
+    Backend dispatch mirrors kernels/ops.py: on TPU (or with an explicit
+    mode="interpret") the Pallas paged kernel DMAs pages via scalar-
+    prefetched block tables; the XLA path (the CPU default, or an explicit
+    mode="xla") gathers the slot's pages into logical order and
     runs the same `_dense_attention` the dense decode path uses — so paged
     and dense decode agree bitwise for equal cache extents."""
-    H, KV = q.shape[2], k_pool.shape[2]
-    if mode is None:
-        from repro.kernels.ops import resolve_mode
-        mode = resolve_mode(None)
-    if mode in ("pallas", "interpret") and H % KV == 0:
+    from repro.kernels.ops import resolve_mode
+    mode = resolve_mode(mode)
+    if mode != "xla":
+        _check_grouped(q, k_pool)
         from repro.kernels import ops
         o = ops.paged_attention(q[:, 0], k_pool, v_pool, block_tables,
                                 lengths, window=window, mode=mode)
@@ -588,11 +601,10 @@ def paged_decode_attend_quant(q: Array, k_pool: Array, v_pool: Array,
     `_dense_attention` as the bf16 fallback — elementwise it is exactly
     the bf16 fallback applied to the dequantized pool."""
     from repro.serve.kv_cache import kv_decode
-    H, KV = q.shape[2], k_pool.shape[2]
-    if mode is None:
-        from repro.kernels.ops import resolve_mode
-        mode = resolve_mode(None)
-    if mode in ("pallas", "interpret") and H % KV == 0:
+    from repro.kernels.ops import resolve_mode
+    mode = resolve_mode(mode)
+    if mode != "xla":
+        _check_grouped(q, k_pool)
         from repro.kernels import ops
         o = ops.paged_attention_quant(q[:, 0], k_pool, v_pool, k_scale,
                                       v_scale, block_tables, lengths,

@@ -55,6 +55,10 @@ class BuildPlan:
     # prefill cache capacity (0 -> prompt length); serving engines set
     # prompt+max_new so decode can continue without ring eviction
     prefill_cache_len: int = 0
+    # paged decode attention dispatch (kernels/ops.resolve_mode): None =
+    # the backend's default (Pallas on TPU); "xla" / "interpret" only by
+    # explicit choice — the on-chip oracle comparison and CPU tests
+    kernel_mode: Optional[str] = None
     constrain: Callable[[Array, str], Array] = _ident_constrain
 
     def heads_padded(self, cfg) -> int:
@@ -343,13 +347,14 @@ def layer_decode_paged(p: dict, x: Array, cfg, plan: BuildPlan,
             kv_bits=plan.kv_bits)
         o = attn_mod.paged_decode_attend_quant(
             q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths,
-            hmap, window=cfg.sliding_window, kv_bits=plan.kv_bits)
+            hmap, window=cfg.sliding_window, kv_bits=plan.kv_bits,
+            mode=plan.kernel_mode)
         x = x + attn_mod.out_project(p["attn"], o)
         x = x + _decode_ffn(p, x, cfg, plan)
         return x, k_pool, v_pool, k_scale, v_scale
     k_pool, v_pool = paged_insert(k_pool, v_pool, k, v, block_tables, pos)
     o = paged_decode_attend(q, k_pool, v_pool, block_tables, lengths, hmap,
-                            window=cfg.sliding_window)
+                            window=cfg.sliding_window, mode=plan.kernel_mode)
     x = x + attn_mod.out_project(p["attn"], o)
     x = x + _decode_ffn(p, x, cfg, plan)
     return x, k_pool, v_pool

@@ -166,7 +166,6 @@ class Runtime:
             step_fn = lambda p, pool, bt, t, pos: decode_step_paged(  # noqa: E731
                 p, cfg, plan, pool, bt, t, pos)
         else:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             nbl = sc.num_blocks // tp
             sp = self._specs
@@ -181,11 +180,11 @@ class Runtime:
                 btl = jnp.maximum(bt - me * nbl, 0)
                 return decode_step_paged(p, cfg, plan, pool, btl, t, pos)
 
-            step_fn = shard_map(
+            step_fn = jax.shard_map(
                 local_step, mesh=mesh,
                 in_specs=(P(), sp["pool"], sp["bt"], sp["tok"], sp["pos"]),
                 out_specs=(sp["logits"], sp["pool"]),
-                check_rep=False)
+                check_vma=False)
         self._decode = guard_jit(
             step_fn, name="serve.decode_step", max_traces=1,
             donate_argnums=(1,))
@@ -242,7 +241,6 @@ class Runtime:
                                      kv_bits=kv_bits)
 
             if self.mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
                 nbl = self.serve_cfg.num_blocks // self._tp
                 sp = self._specs
@@ -258,10 +256,10 @@ class Runtime:
                     tbl = jnp.where(owned, table_row - me * nbl, nbl)
                     return write(pool, k_seq, v_seq, kv_pos, tlen, tbl)
 
-                inner = shard_map(
+                inner = jax.shard_map(
                     write_sharded, mesh=self.mesh,
                     in_specs=(sp["pool"], P(), P(), P(), P(), P()),
-                    out_specs=sp["pool"], check_rep=False)
+                    out_specs=sp["pool"], check_vma=False)
             else:
                 inner = write
             fn = guard_jit(inner, name=f"serve.prefill_write[{cache_len}]",

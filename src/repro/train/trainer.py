@@ -44,14 +44,13 @@ class Trainer:
             # Trainer's whole batch is one shard, so this exercises the
             # int8-EF quantize/carry path end-to-end; multi-shard
             # deployments wire their own shard_map (see train_step.py)
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.dist import data_mesh
             step = make_train_step(cfg, plan, run_cfg, self.adamw_cfg,
                                    axis_name="data")
             self.step_fn = jax.jit(
-                shard_map(step, mesh=data_mesh(1), in_specs=(P(), P()),
-                          out_specs=(P(), P()), check_rep=False),
+                jax.shard_map(step, mesh=data_mesh(1), in_specs=(P(), P()),
+                              out_specs=(P(), P()), check_vma=False),
                 donate_argnums=(0,))
         else:
             self.step_fn = jax.jit(

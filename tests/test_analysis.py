@@ -108,10 +108,12 @@ def test_donation_audit_accepts_real_aliasing():
 
 
 def test_donation_audit_catches_dtype_mismatch():
-    """Donating an f32 input to a program with only an int32 output: JAX
-    drops the donation with a warning most callers never see — the audit
-    reads the alias table and fails loudly."""
-    f = jax.jit(lambda x: x.astype(jnp.int32), donate_argnums=(0,))
+    """Donating an f32 input to a program whose only output is bf16 (no
+    output of the donated buffer's byte size): JAX drops the donation with
+    a warning most callers never see — the audit reads the alias table and
+    fails loudly. (A same-size s32 output is no longer enough: XLA now
+    aliases a donated f32[16,16] to an s32[16,16] result.)"""
+    f = jax.jit(lambda x: x.astype(jnp.bfloat16), donate_argnums=(0,))
     x = jnp.ones((16, 16))
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
@@ -126,7 +128,7 @@ def test_donation_audit_maps_pytree_args():
     """Donated pytree arg: every leaf must alias; one mismatched leaf in
     the donated tree is caught, leaves of undonated args are ignored."""
     def g(state, y):
-        return {"a": state["a"] * 2, "b": state["b"].astype(jnp.int32)}, y
+        return {"a": state["a"] * 2, "b": state["b"].astype(jnp.bfloat16)}, y
 
     f = jax.jit(g, donate_argnums=(0,))
     state = {"a": jnp.ones((4, 4)), "b": jnp.ones((3,))}

@@ -14,10 +14,14 @@ KEY = jax.random.PRNGKey(42)
 
 
 @pytest.mark.parametrize("mkn", [(64, 256, 128), (128, 512, 128),
-                                 (32, 128, 256)])
-@pytest.mark.parametrize("bits", [8, 4])
+                                 (32, 128, 256), (40, 256, 256)])
+@pytest.mark.parametrize("bits", [8, 4, 2])
 @pytest.mark.parametrize("xdtype", [jnp.float32, jnp.bfloat16])
 def test_quant_matmul_sweep(mkn, bits, xdtype):
+    """Interpret-mode kernel vs the unpacked oracle at every storage
+    density (cpb 1/2/4, planar packing), multi-block grids and a ragged
+    M that the wrapper pads."""
+    from repro.core.quantizer import pack_codes
     M, K, N = mkn
     k1, k2 = jax.random.split(jax.random.fold_in(KEY, M + K + N + bits))
     x = jax.random.normal(k1, (M, K), xdtype)
@@ -25,12 +29,11 @@ def test_quant_matmul_sweep(mkn, bits, xdtype):
     scale = jax.random.uniform(k1, (N,), jnp.float32, 0.01, 0.05)
     z = jax.random.randint(k2, (N,), -(2 ** (bits - 1)), 0).astype(jnp.int32)
     want = ref.quant_matmul_ref(x.astype(jnp.float32), u, scale, z)
-    codes = u
-    if bits == 4:
-        from repro.core.quantizer import pack_int4
-        codes = pack_int4(u)
-    got = quant_matmul_pallas(x, codes, scale, z, bits=bits, bm=32, bn=64,
-                              bk=128, interpret=True)
+    codes, cpb = pack_codes(u, bits)
+    assert cpb == {8: 1, 4: 2, 2: 4}[bits]
+    got = quant_matmul_pallas(x, codes, scale, z, bits=bits, cpb=cpb,
+                              bm=32, bn=64 // max(1, cpb // 2), bk=128,
+                              interpret=True)
     rel = float(jnp.max(jnp.abs(got - want)) /
                 (jnp.max(jnp.abs(want)) + 1e-9))
     assert rel < 3e-2, rel  # bf16 MXU accumulation tolerance

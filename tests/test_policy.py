@@ -14,7 +14,7 @@ from repro.core import (QuantPolicy, QuantSpec, allocate_bits, as_policy,
                         policy_from_budget, quantize_model, serving_params)
 from repro.core.pipeline import is_qtensor, qtensor_bits
 from repro.core.quantizer import (codes_per_byte, pack_codes, pack_int2,
-                                  unpack_codes, unpack_int2)
+                                  pack_int4, unpack_codes, unpack_int2)
 from repro.models import BuildPlan, init_params
 
 KEY = jax.random.PRNGKey(0)
@@ -89,6 +89,24 @@ def test_pack_int2_roundtrip():
     p = pack_int2(u)
     assert p.shape == (16, 6)
     assert bool(jnp.all(unpack_int2(p) == u))
+
+
+@pytest.mark.parametrize("cpb", [2, 4])
+def test_planar_pack_layout(cpb):
+    """Planar packing: bit field f of packed column c is code
+    f·(n/cpb) + c — a packed tile widens into cpb contiguous planes with
+    shifts and masks alone (what the TPU kernels rely on)."""
+    rs = np.random.RandomState(cpb)
+    w = 8 // cpb
+    u = jnp.asarray(rs.randint(0, 2 ** w, (6, 8 * cpb)), jnp.uint8)
+    p = np.asarray(pack_int4(u) if cpb == 2 else pack_int2(u))
+    n = u.shape[1] // cpb
+    assert p.shape == (6, n)
+    un = np.asarray(u)
+    for f in range(cpb):
+        np.testing.assert_array_equal((p >> (w * f)) & (2 ** w - 1),
+                                      un[:, f * n:(f + 1) * n])
+    assert bool(jnp.all(unpack_codes(jnp.asarray(p), cpb) == u))
 
 
 def test_pack_codes_dispatch_and_alignment_fallback():

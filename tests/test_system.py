@@ -71,3 +71,26 @@ def test_quantize_then_serve_roundtrip(trained):
     out = eng.generate_batch(prompts, max_new_tokens=8)
     assert out.shape == (2, 8)
     assert (out >= 0).all() and (out < cfg.vocab_size).all()
+
+
+@pytest.mark.parametrize("kv_bits", ["0", "8"])
+def test_launcher_quantize_file_feeds_serve(tmp_path, kv_bits):
+    """The quantizer's packed file is what the server loads: `quantize
+    --save-packed F` then `serve --load-quantized F` answers every request
+    from packed codes (the seam the two launchers share)."""
+    from repro.launch import quantize, serve
+    ckpt = str(tmp_path / "q.qpk")
+    arch = ["--arch", "h2o-danube-1.8b", "--smoke"]
+    q = quantize.main(arch + [
+        "--method", "comq_blocked", "--bits", "4", "--sweeps", "1",
+        "--calib-batch", "2", "--calib-seq", "48",
+        "--out-dir", str(tmp_path / "ck"), "--save-packed", ckpt])
+    assert q["comq_vs_rtn_error_improvement"] > 0
+    assert np.isfinite(q["fp_loss"]) and np.isfinite(q["quant_loss"])
+    s = serve.main(arch + [
+        "--load-quantized", ckpt, "--num-requests", "4", "--mixed",
+        "--prompt-len", "24", "--max-new", "6", "--stagger", "2",
+        "--kv-bits", kv_bits])
+    assert s["packed_qt"] and s["requests"] == 4
+    assert s["out_tokens"] == 4 * 6
+    assert s["finish_reasons"] == ["length"] * 4
