@@ -145,7 +145,7 @@ def run():
     def obs_step(i):
         # mirror of Runtime.step()'s per-step instrumentation with all
         # N_REQ slots live (the traced workload's steady state)
-        with tr.span("decode_step", device=True, step=i, slots=N_REQ):
+        with tr.span("decode_step", step=i, slots=N_REQ):
             pass
         now_us = time.time() * 1e6
         for s in range(N_REQ):

@@ -55,9 +55,9 @@ HOT_ZONES: Dict[str, Tuple[str, ...]] = {
     "dist/calibrate.py": ("sharded_gram", "sharded_batched_gram"),
     # observability hooks run once per token/leaf from inside the zones
     # above — they must stay append-only host work (DESIGN.md §10.3)
-    "obs/trace.py": ("Tracer.span", "Tracer.instant",
-                     "Tracer.request_event", "Tracer.token_event",
-                     "Span.__exit__"),
+    "obs/trace.py": ("Tracer.span", "Tracer.request_event",
+                     "Tracer.token_event", "Span.__init__",
+                     "Span.__enter__", "Span.__exit__"),
     "obs/metrics.py": ("Counter.inc", "Gauge.set", "Gauge.add",
                        "Histogram.observe"),
 }

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantizer import EPS, QuantSpec
+from repro.obs.trace import NULL_TRACER
 
 Array = jax.Array
 
@@ -63,10 +64,13 @@ class GuardEvent:
 
 class GuardContext:
     """Collects GuardEvents across one quantize_model walk. A disabled
-    context makes every guard hook a no-op (and bit-exact)."""
+    context makes every guard hook a no-op (and bit-exact). `tracer`
+    (obs.Tracer) gets a `quant.sync.<site>` span around each host sync
+    the guards make."""
 
-    def __init__(self, enabled: bool = True):
+    def __init__(self, enabled: bool = True, tracer=None):
         self.enabled = enabled
+        self.tracer = tracer or NULL_TRACER
         self.events: List[GuardEvent] = []
 
     def record(self, layer: int, name: str, kind: str, warn: bool = True,
@@ -246,7 +250,9 @@ def guarded_solve(h: Array, w2d: Array, spec: QuantSpec, method: str, *,
         for mult in (0.0,) + DAMP_MULTS:
             hd = h if mult == 0.0 else damp_hessian(h, mult, diag_mean)
             r = solve_fn(hd, w2d, spec, meth, block=block, schedule=schedule)
-            if result_ok(r, ref_err):
+            with gctx.tracer.span("quant.sync.solve_verdict"):
+                ok = result_ok(r, ref_err)
+            if ok:
                 if mult:
                     for nm in names:
                         gctx.record(layer, nm, "damping_escalated",

@@ -168,13 +168,6 @@ class LayerReport:
     # for the full records
     guard: str = ""
 
-    @property
-    def seconds(self) -> float:
-        """Pre-PR-9 alias. The old field recorded dispatch time since the
-        sync-free walk landed but consumers still read it as wall time —
-        use `dispatch_seconds` or `wall_seconds` explicitly."""
-        return self.dispatch_seconds
-
 
 @dataclass
 class QuantReport:
@@ -288,14 +281,16 @@ def _uniform(specs) -> bool:
     return all(s == specs[0] for s in specs)
 
 
-def _results_finite(results) -> bool:
+def _results_finite(results, tracer) -> bool:
     """Host bool: every (qt, eb, ea, secs) row has finite scales and
-    errors — one batched transfer (the post-solve guard sentinel)."""
+    errors — one batched transfer (the post-solve guard sentinel), under
+    a `quant.sync.results_finite` span of `tracer`."""
     flags = [jnp.all(jnp.isfinite(qt["scale"]))
              & jnp.isfinite(jnp.asarray(eb, jnp.float32))
              & jnp.isfinite(jnp.asarray(ea, jnp.float32))
              for qt, eb, ea, _ in results]
-    return bool(jax.device_get(jnp.all(jnp.stack(flags))))  # comq: allow(host-sync) one batched finiteness verdict
+    with tracer.span("quant.sync.results_finite"):
+        return bool(jax.device_get(jnp.all(jnp.stack(flags))))  # comq: allow(host-sync) one batched finiteness verdict
 
 
 def _solve_group(ws, h: Array, specs, method: str,
@@ -336,7 +331,8 @@ def _solve_group(ws, h: Array, specs, method: str,
     if names is None:
         names = [f"leaf{i}" for i in range(len(ws))]
     if guarding:
-        n_bad_h, n_dead, n_bad_ws = _guards.gram_health(h, w2ds)
+        with gctx.tracer.span("quant.sync.gram_health"):
+            n_bad_h, n_dead, n_bad_ws = _guards.gram_health(h, w2ds)
         if n_bad_h:
             h = jnp.where(jnp.isfinite(h), h, jnp.zeros((), h.dtype))
             for nm in names:
@@ -377,7 +373,7 @@ def _solve_group(ws, h: Array, specs, method: str,
                 qt = make_qtensor(q, delta, z_lo, w.shape, bits=spec.bits)
                 out.append((qt, _norm_of(e2b), _norm_of(e2a),
                             time.time() - t0))
-        if guarding and not _results_finite(out):
+        if guarding and not _results_finite(out, gctx.tracer):
             # the sharded program has no guard hooks — redo this group
             # replicated under the full guarded chain
             for nm in names:
@@ -490,17 +486,18 @@ def _solve_group_experts(ws, hs: Array, specs, method: str, *,
         return run(hs, method)
     if names is None:
         names = [f"leaf{i}" for i in range(len(ws))]
-    n_bad = _guards.nonfinite_count(hs)
+    with gctx.tracer.span("quant.sync.gram_health"):
+        n_bad = _guards.nonfinite_count(hs)
     if n_bad:
         hs = jnp.where(jnp.isfinite(hs), hs, jnp.zeros((), hs.dtype))
         for nm in names:
             gctx.record(layer, nm, "nonfinite_gram", count=n_bad)
     out = run(hs, method)
-    if _results_finite(out):
+    if _results_finite(out, gctx.tracer):
         return out
     for mult in _guards.DAMP_MULTS:
         out = run(_guards.damp_hessian(hs, mult), method)
-        if _results_finite(out):
+        if _results_finite(out, gctx.tracer):
             for nm in names:
                 gctx.record(layer, nm, "damping_escalated", mult=mult)
             return out
@@ -594,7 +591,8 @@ class _RunCtx:
         activations before they poison the Gram."""
         if self.gctx is None or not self.gctx.enabled:
             return tap
-        n_bad = _guards.nonfinite_count(tap)
+        with self.tracer.span("quant.sync.tap_finite"):
+            n_bad = _guards.nonfinite_count(tap)
         if n_bad:
             tap = jnp.where(jnp.isfinite(tap), tap, jnp.zeros((), tap.dtype))
             for nm in names:
@@ -678,19 +676,21 @@ def _timed_solve(ctx: "_RunCtx", layer: int, tapname: str, names,
     """Run one tap group's solve under a `leaf_solve` tracer span and
     extend each (qt, eb, ea, secs) row with a span-derived wall_seconds.
 
-    With tracing on, the span blocks on the solved codes before closing,
-    so its duration — split evenly across the group like dispatch secs —
-    is true solve wall time. With tracing off the thunk runs bare and the
-    walk stays exactly sync-free (wall 0.0 = unmeasured)."""
+    With tracing on, the span blocks on the solved codes before closing
+    (a `quant.sync.trace_block` span: a sync the untraced walk does not
+    make), so its duration — split evenly across the group like dispatch
+    secs — is true solve wall time. With tracing off the thunk runs bare
+    and the walk makes no sync of its own (wall 0.0 = unmeasured)."""
     if not ctx.tracer.enabled:
         results = solve_thunk()
         ctx.m_leaves.inc(len(results))
         return [r + (0.0,) for r in results]
-    with ctx.tracer.span("leaf_solve", device=True, layer=layer,
-                         tap=tapname, leaves=",".join(names)) as sp:
+    with ctx.tracer.span("leaf_solve", layer=layer, tap=tapname,
+                         leaves=",".join(names)) as sp:
         results = solve_thunk()
-        # comq: allow(host-sync) span wall time: tracing-on path only
-        jax.block_until_ready([qt["codes"] for qt, *_ in results])
+        with ctx.tracer.span("quant.sync.trace_block"):
+            # comq: allow(host-sync) span wall time: tracing-on path only
+            jax.block_until_ready([qt["codes"] for qt, *_ in results])
         wall = sp.elapsed_s / max(len(results), 1)
     ctx.m_leaves.inc(len(results))
     return [r + (wall,) for r in results]
@@ -758,14 +758,16 @@ def _quantize_layer_leaves(lp, taps, tapmap, resolve, method: str,
         for _ in names:
             ctx.fault("leaf_solve")
         if tapname.startswith("expert"):
-            hs = cache.batched(tapname, tap)
+            with ctx.tracer.span("quant.gram", layer=layer_idx, tap=tapname):
+                hs = cache.batched(tapname, tap)
             results = _timed_solve(
                 ctx, layer_idx, tapname, names,
                 lambda: _solve_group_experts(ws, hs, specs, method,
                                              gctx=ctx.gctx, layer=layer_idx,
                                              names=names))
         else:
-            h = cache.gram(tapname, tap)
+            with ctx.tracer.span("quant.gram", layer=layer_idx, tap=tapname):
+                h = cache.gram(tapname, tap)
             results = _timed_solve(
                 ctx, layer_idx, tapname, names,
                 lambda: _solve_group(ws, h, specs, method,
@@ -820,14 +822,16 @@ def _staged_cb(lp, groups, taps, resolve, method: str,
         for _ in names:
             ctx.fault("leaf_solve")
         if tapname.startswith("expert"):
-            hs = batched_fn(tap)
+            with ctx.tracer.span("quant.gram", layer=layer_idx, tap=tapname):
+                hs = batched_fn(tap)
             results = _timed_solve(
                 ctx, layer_idx, tapname, names,
                 lambda: _solve_group_experts(ws, hs, specs, method,
                                              gctx=ctx.gctx, layer=layer_idx,
                                              names=names))
         else:
-            h = gram_fn(tap)
+            with ctx.tracer.span("quant.gram", layer=layer_idx, tap=tapname):
+                h = gram_fn(tap)
             results = _timed_solve(
                 ctx, layer_idx, tapname, names,
                 lambda: _solve_group(ws, h, specs, method,
@@ -979,13 +983,14 @@ def quantize_model(params, cfg, plan, tokens: Array, spec,
       (gram_accumulate / leaf_solve / ckpt_write / kill / nan_tap);
       progress_cb(layer) fires after each durably-journaled layer (the
       supervisor's progress signal, e.g. ft.Heartbeat.beat).
-    * tracer (obs.Tracer) records layer / leaf_solve spans — with a
-      tracer each tap group's span blocks on its solved codes so
-      LayerReport.wall_seconds is true wall time; without one the walk
-      stays sync-free. metrics (obs.MetricsRegistry) accumulates
-      quant.* counters/histograms and, under a mesh, the
-      dist.bytes_all_reduced counter. Both default to disabled null
-      singletons with zero cost (DESIGN.md §10).
+    * tracer (obs.Tracer) records layer, quant.gram and leaf_solve
+      spans and a quant.sync.<site> span around each host sync — with a
+      tracer each tap group's span blocks on its solved codes
+      (quant.sync.trace_block) so LayerReport.wall_seconds is true wall
+      time; without one the walk adds no sync of its own. metrics
+      (obs.MetricsRegistry) accumulates quant.* counters/histograms and,
+      under a mesh, the dist.bytes_all_reduced counter. Both default to
+      disabled null singletons with zero cost (DESIGN.md §10).
 
     Returns (qparams, QuantReport). qparams has QTensor leaves (each
     carrying its resolved bit width); use `dequantize_tree` (or the
@@ -1037,7 +1042,7 @@ def quantize_model(params, cfg, plan, tokens: Array, spec,
                                 propagation=propagation,
                                 n_layers=cfg.n_layers)
 
-    gctx = GuardContext(enabled=guards)
+    gctx = GuardContext(enabled=guards, tracer=tracer)
     ctx = _RunCtx(method, gctx=gctx, journal=qj, solved=solved,
                   injector=injector, progress_cb=progress_cb,
                   tracer=tracer, metrics=metrics)
@@ -1127,7 +1132,9 @@ def quantize_model(params, cfg, plan, tokens: Array, spec,
                         ctx.poison_tap(apply_norm(params["final_norm"], x,
                                                   cfg)), -1, names)
                     ctx.fault("leaf_solve")
-                    h = gram_fn(xn)
+                    with ctx.tracer.span("quant.gram", layer=-1,
+                                         tap="unembed_in"):
+                        h = gram_fn(xn)
                     results = _timed_solve(
                         ctx, -1, "unembed_in", names,
                         lambda: _solve_group([params["unembed"]], h, specs,

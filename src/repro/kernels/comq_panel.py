@@ -97,6 +97,7 @@ def _panel_call(h_bb: Array, s0: Array, qf: Array, delta: Array,
             jax.ShapeDtypeStruct((B, n), jnp.float32),
         ],
         interpret=interpret,
+        name="comq_panel",
     )(h_bb.astype(jnp.float32), s0.astype(jnp.float32),
       qf.astype(jnp.float32), delta.reshape(1, n), z_lo.reshape(1, n),
       z_hi.reshape(1, n), hdiag.astype(jnp.float32).reshape(B, 1))

@@ -109,6 +109,7 @@ def flash_attention_pallas(q: Array, k: Array, v: Array, *,
             _vmem((bq, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 

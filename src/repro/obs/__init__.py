@@ -3,8 +3,9 @@
 One subsystem, three concerns, shared across quantize + serve:
 
 * `obs/trace.py`   — `Tracer`: nestable host spans + request lifecycle
-  events, emitted as Chrome-trace/Perfetto JSON; `jax.profiler`
-  TraceAnnotation bridging so host spans line up with device timelines.
+  events, emitted as Chrome-trace/Perfetto JSON; every span is also a
+  `jax.profiler` TraceAnnotation, so host spans line up with device
+  timelines.
 * `obs/metrics.py` — `MetricsRegistry`: counters / gauges / histograms
   with a JSONL event-stream sink and Prometheus text exposition.
 * `obs/timeline.py` — per-request serve timelines (submit → admit →

@@ -11,10 +11,12 @@
   the raw material `obs/timeline.py` reconstructs per-request serve
   timelines from (and dedups by rid across crash-replay restarts).
 
-Device bridging: when a span is opened with `device=True` the tracer
-also enters `jax.profiler.TraceAnnotation(label)`, which is a cheap
-TraceMe when no profiler is attached and annotates the device timeline
-when one is — so host spans and XLA slices line up in one viewer.
+One clock: every span also enters `jax.profiler.TraceAnnotation(name)`
+under its bare name, on the thread that opens it. With no profiler
+attached that is a cheap TraceMe; with one it puts the span in the
+profiler's own trace beside the device's operations, so idle time on the
+device can be pinned on the host phase it falls in. Span args stay in
+the JSON events only, so trace readers match exact names.
 
 Timestamps are epoch microseconds (`time.time()*1e6`) so traces written
 by different processes — e.g. restart generations of a crash-replay run
@@ -56,9 +58,6 @@ class _NullTracer:
     def span(self, name: str, **args: Any) -> _NullSpan:
         return _NULL_SPAN
 
-    def instant(self, name: str, **args: Any) -> None:
-        return None
-
     def request_event(self, kind: str, rid: int, **args: Any) -> None:
         return None
 
@@ -78,23 +77,23 @@ class Span:
     __slots__ = ("_tracer", "name", "args", "_t0_epoch_us", "_t0_perf",
                  "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any],
-                 annotation: Any = None):
+    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
+        # imported here, so importing this module never imports jax (the
+        # report and validate CLIs are pure python)
+        from jax.profiler import TraceAnnotation
         self._tracer = tracer
         self.name = name
         self.args = args
-        self._annotation = annotation
+        self._annotation = TraceAnnotation(name)
         self._t0_epoch_us = time.time() * 1e6
         self._t0_perf = time.perf_counter()
 
     def __enter__(self) -> "Span":
-        if self._annotation is not None:
-            self._annotation.__enter__()
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
-        if self._annotation is not None:
-            self._annotation.__exit__(*exc)
+        self._annotation.__exit__(*exc)
         dur_us = (time.perf_counter() - self._t0_perf) * 1e6
         self._tracer._events.append(
             ("X", self.name, self._t0_epoch_us, dur_us,
@@ -122,22 +121,15 @@ class Tracer:
         self.run = run
         self.pid = os.getpid() if pid is None else pid
         # raw entries: ("X", name, ts_us, dur_us, tid, args) for spans,
-        # ("i", name, cat, ts_us, tid, args) for instants
+        # ("i", name, cat, ts_us, tid, args) for request events
         self._events: List[tuple] = []
 
     # -- emission ------------------------------------------------------
-    def span(self, name: str, *, device: bool = False, **args: Any) -> Span:
-        """Open a nestable span. `device=True` additionally enters a
-        `jax.profiler.TraceAnnotation` so the label shows up on the
-        device timeline when a profiler is attached."""
-        annotation = None
-        if device:
-            annotation = _trace_annotation(name)
-        return Span(self, name, args, annotation)
-
-    def instant(self, name: str, **args: Any) -> None:
-        self._events.append(("i", name, "instant", time.time() * 1e6,
-                             threading.get_ident(), args))
+    def span(self, name: str, **args: Any) -> Span:
+        """Open a nestable span, also a profiler TraceAnnotation of the
+        bare `name`. `args` go into the JSON event only; a span's args may
+        be added to before it closes (`span.args.update(...)`)."""
+        return Span(self, name, args)
 
     def request_event(self, kind: str, rid: int, **args: Any) -> None:
         """Instant event in the `request` category; the per-request
@@ -204,13 +196,3 @@ def next_trace_path(directory: str, prefix: str) -> str:
              if f.startswith(prefix + ".g") and f.endswith(".trace.json")])
     return os.path.join(directory, f"{prefix}.g{n}.trace.json")
 
-
-def _trace_annotation(label: str):
-    """Lazy `jax.profiler.TraceAnnotation` — imported at span-open so
-    building a Tracer never drags in jax (the validator/report CLIs are
-    pure python)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:       # pragma: no cover - jax always present in CI
-        return None
-    return TraceAnnotation(label)

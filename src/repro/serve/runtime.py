@@ -55,6 +55,17 @@ from repro.serve.sampler import sample_batch_seeded
 from repro.serve.scheduler import DEFAULT_BUCKETS, Request, Scheduler
 
 
+def sample(logits, seed, count, temperature, top_k, top_p):
+    """Per-slot seeded sampling of one decode step's tokens."""
+    return sample_batch_seeded(logits, seed, count, temperature=temperature,
+                               top_k=top_k, top_p=top_p)
+
+
+def argmax(logits):
+    """Greedy tokens of one decode step."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
     max_slots: int = 4
@@ -161,16 +172,19 @@ class Runtime:
         self._write_cache: Dict[int, object] = {}
         # retrace budgets (analysis/retrace.py): the decode program compiles
         # exactly once per Runtime — a second trace means shape-unstable
-        # decode state and would serialize every step behind a compile
+        # decode state and would serialize every step behind a compile.
+        # Programs are named after their functions (XLA modules
+        # jit_decode_step, jit_argmax, jit_sample), so a profiler trace
+        # finds them by name.
         if mesh is None:
-            step_fn = lambda p, pool, bt, t, pos: decode_step_paged(  # noqa: E731
-                p, cfg, plan, pool, bt, t, pos)
+            def decode_step(p, pool, bt, t, pos):
+                return decode_step_paged(p, cfg, plan, pool, bt, t, pos)
         else:
             from jax.sharding import PartitionSpec as P
             nbl = sc.num_blocks // tp
             sp = self._specs
 
-            def local_step(p, pool, bt, t, pos):
+            def decode_step(p, pool, bt, t, pos):
                 # block tables carry *global* page ids; a shard's slots
                 # only ever hold pages it owns (partitioned allocator), so
                 # localizing is a subtract — the clamp only touches the
@@ -180,22 +194,19 @@ class Runtime:
                 btl = jnp.maximum(bt - me * nbl, 0)
                 return decode_step_paged(p, cfg, plan, pool, btl, t, pos)
 
-            step_fn = jax.shard_map(
-                local_step, mesh=mesh,
+            decode_step = jax.shard_map(
+                decode_step, mesh=mesh,
                 in_specs=(P(), sp["pool"], sp["bt"], sp["tok"], sp["pos"]),
                 out_specs=(sp["logits"], sp["pool"]),
                 check_vma=False)
         self._decode = guard_jit(
-            step_fn, name="serve.decode_step", max_traces=1,
+            decode_step, name="serve.decode_step", max_traces=1,
             donate_argnums=(1,))
-        self._sample = guard_jit(
-            lambda lg, sd, ct, t, tk, tp: sample_batch_seeded(
-                lg, sd, ct, temperature=t, top_k=tk, top_p=tp),
-            name="serve.sample", per_signature=True)
+        self._sample = guard_jit(sample, name="serve.sample",
+                                 per_signature=True)
         # all-greedy fast path: skips the (B, V) sort/softmax machinery
-        self._argmax = guard_jit(
-            lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32),
-            name="serve.argmax", per_signature=True)
+        self._argmax = guard_jit(argmax, name="serve.argmax",
+                                 per_signature=True)
         # device-resident block tables, re-uploaded only on change: steady
         # greedy decode keeps the table constant, so the per-step
         # host->device copy is pure overhead the moment tables settle
@@ -441,72 +452,80 @@ class Runtime:
         of tokens emitted (prefill first-tokens included)."""
         if self.injector is not None:
             self.injector.check("kill", SimulatedKill)
+        # one span per host phase (obs/trace.py): each is also a profiler
+        # TraceMe, so device idle time can be pinned on the phase it falls in
+        tracer = self.tracer
         emitted = 0
-        for req in self.scheduler.admit(on_preempt=self._clear_slot):
-            emitted += self._admit_one(req)
+        with tracer.span("serve.admit"):
+            for req in self.scheduler.admit(on_preempt=self._clear_slot):
+                emitted += self._admit_one(req)
         bs = self.serve_cfg.block_size
-        for s, req in sorted(self.scheduler.running.items()):
-            if req.state != "running":      # preempted earlier this pass
-                continue
-            needed = int(self._pos[s]) // bs + 1
-            self.scheduler.ensure_pages(req, needed,
-                                        on_preempt=self._clear_slot)
-        running = dict(self.scheduler.running)
+        with tracer.span("serve.pages"):
+            for s, req in sorted(self.scheduler.running.items()):
+                if req.state != "running":      # preempted earlier this pass
+                    continue
+                needed = int(self._pos[s]) // bs + 1
+                self.scheduler.ensure_pages(req, needed,
+                                            on_preempt=self._clear_slot)
+            running = dict(self.scheduler.running)
+            for s, req in running.items():
+                row = np.asarray(req.blocks, np.int32)       # grown tables
+                if not np.array_equal(self._bt[s, :len(row)], row):
+                    self._bt[s, :len(row)] = row
+                    self._bt_dirty = True
+            # block tables only cross to the device when they changed
+            # (admit, retire, preempt, page growth) — steady decode re-uses
+            # the device-resident copy instead of re-uploading (B, maxb)
+            if running and (self._bt_dirty or self._bt_dev is None):
+                self._bt_dev = jnp.asarray(self._bt)
+                self._bt_dirty = False
         if not running:
             return emitted
-        for s, req in running.items():
-            row = np.asarray(req.blocks, np.int32)       # grown tables
-            if not np.array_equal(self._bt[s, :len(row)], row):
-                self._bt[s, :len(row)] = row
-                self._bt_dirty = True
         if self.injector is not None:
             self.injector.check("decode_step")
         t0 = time.time()
-        # block tables only cross to the device when they changed (admit,
-        # retire, preempt, page growth) — steady decode re-uses the
-        # device-resident copy instead of re-uploading (B, maxb) per step
-        if self._bt_dirty or self._bt_dev is None:
-            self._bt_dev = jnp.asarray(self._bt)
-            self._bt_dirty = False
-        # the span brackets dispatch + the token pull the loop needs
-        # anyway — no extra syncs, and device=True annotates the XLA
-        # timeline so profiler slices line up with this host span
-        with self.tracer.span("decode_step", device=True,
-                              step=self.steps, slots=len(running)):
-            logits, self.pool = self._decode(
-                self.params, self.pool, self._bt_dev,
-                jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos))
-            if self._any_sampling:
-                toks = np.asarray(self._sample(  # comq: allow(host-sync) decode loop needs the tokens
-                    logits, jnp.asarray(self._seed), jnp.asarray(self._count),
-                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp)))
-            else:
-                toks = np.asarray(self._argmax(logits))  # comq: allow(host-sync) decode loop needs the tokens
+        # decode_step brackets dispatch + the token pull the loop needs
+        # anyway — no extra syncs
+        with tracer.span("decode_step", step=self.steps, slots=len(running)):
+            with tracer.span("serve.dispatch"):
+                logits, self.pool = self._decode(
+                    self.params, self.pool, self._bt_dev,
+                    jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos))
+            with tracer.span("serve.pull"):
+                if self._any_sampling:
+                    toks = np.asarray(self._sample(  # comq: allow(host-sync) decode loop needs the tokens
+                        logits, jnp.asarray(self._seed),
+                        jnp.asarray(self._count), jnp.asarray(self._temp),
+                        jnp.asarray(self._topk), jnp.asarray(self._topp)))
+                else:
+                    toks = np.asarray(self._argmax(logits))  # comq: allow(host-sync) decode loop needs the tokens
         now = time.time()
         self.steps += 1
         self.decode_seconds += now - t0
-        for s, req in running.items():
-            self._emit(req, int(toks[s]), now)
-            emitted += 1
-            self._pos[s] += 1
-            self._tok[s] = int(toks[s])
-            self._count[s] += 1
-            # stop-token or length: slot + pages free on this very step, so
-            # queued requests can admit next step. Tokens after the stop
-            # are never emitted — metrics count what was actually streamed.
-            if req.finished():
-                self._retire(req)
-        # live-token occupancy: pages actually holding written K/V rows —
-        # under "reserve" this is what full-lifetime reservation caps
-        live = sum(blocks_for(int(self._pos[s]), bs)
-                   for s in range(self.serve_cfg.max_slots)
-                   if self._pos[s] >= 0)
-        self._occ_sum += live / self.allocator.num_blocks
-        self._occ_steps += 1
-        self._m_free.set(self.allocator.num_free)
-        self._m_occ.set(live / self.allocator.num_blocks)
-        self._m_pool_bytes.set(live * self._page_bytes)
+        with tracer.span("serve.emit"):
+            for s, req in running.items():
+                self._emit(req, int(toks[s]), now)
+                emitted += 1
+                self._pos[s] += 1
+                self._tok[s] = int(toks[s])
+                self._count[s] += 1
+                # stop-token or length: slot + pages free on this very
+                # step, so queued requests can admit next step. Tokens
+                # after the stop are never emitted — metrics count what
+                # was actually streamed.
+                if req.finished():
+                    self._retire(req)
+            # live-token occupancy: pages actually holding written K/V
+            # rows — under "reserve" this is what full-lifetime
+            # reservation caps
+            live = sum(blocks_for(int(self._pos[s]), bs)
+                       for s in range(self.serve_cfg.max_slots)
+                       if self._pos[s] >= 0)
+            self._occ_sum += live / self.allocator.num_blocks
+            self._occ_steps += 1
+            self._m_free.set(self.allocator.num_free)
+            self._m_occ.set(live / self.allocator.num_blocks)
+            self._m_pool_bytes.set(live * self._page_bytes)
         return emitted
 
     def run(self) -> Dict[str, object]:

@@ -158,7 +158,6 @@ def test_layer_report_seconds_reset_per_leaf():
     # per-leaf timing keeps the total within the actual elapsed time
     total = sum(r.dispatch_seconds for r in report.layers)
     assert total <= wall + 1e-6, (total, wall)
-    assert all(r.seconds == r.dispatch_seconds for r in report.layers)
     # no tracer: the walk stays sync-free, wall time is unmeasured
     assert all(r.wall_seconds == 0.0 for r in report.layers)
 

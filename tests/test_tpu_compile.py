@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import (paged_attention_pallas,
                                            paged_attention_quant_pallas)
 from repro.kernels.quant_matmul import quant_matmul_pallas
@@ -74,3 +75,15 @@ def test_paged_attention_compiles_for_v5e(one_chip, kv_bits):
     _compile(lambda q, k, v, ks, vs, bt, ln: paged_attention_quant_pallas(
         q, k, v, ks, vs, bt, ln, window=4096, kv_bits=kv_bits), one_chip,
         q, page, page, scale, scale, *tables)
+
+
+def test_flash_attention_keeps_its_name_for_v5e(one_chip):
+    """The kernel's call is named after it in the compiled program, so a
+    profiler trace finds it by name."""
+    q, kv = ((32, 512, 128), jnp.bfloat16), ((8, 512, 128), jnp.bfloat16)
+    hlo = _compile(lambda q, k, v: flash_attention_pallas(q, k, v),
+                   one_chip, q, kv, kv).as_text()
+    calls = [line.split("=", 1)[0].split()[-1].lstrip("%")
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert [c.rsplit(".", 1)[0] for c in calls] == ["flash_attention"]
