@@ -219,18 +219,17 @@ def _step(cfg, plan):
 
 def _layer_fn(cfg, plan):
     """One jitted paged decode layer (the body of decode_step_paged's
-    scan): (layer params, x, block tables, pos, this layer's pool) -> x."""
+    scan): (layer params, x, block tables, pos, the pool, layer index)
+    -> x."""
     import jax
     from repro.core.apply import dequantize_qt_tree
     from repro.models.common import dtype_of
     from repro.models.transformer import layer_decode_paged
 
-    def layer(lp, x, bt, pos, *pool_l):
+    def layer(lp, x, bt, pos, pool, l):
         lp = dequantize_qt_tree(lp, dtype_of(cfg.compute_dtype),
                                 keep_fused=True)
-        k, v, *scales = pool_l
-        return layer_decode_paged(lp, x, cfg, plan, k, v, bt, pos,
-                                  *scales)[0]
+        return layer_decode_paged(lp, x, cfg, plan, pool, l, bt, pos)[0]
     return jax.jit(layer)
 
 
@@ -244,7 +243,6 @@ def _layer_sync(packed, dense, table, cfg, plan, state):
     from repro.models.common import apply_norm
     from repro.models.model import embed_tokens, unembed
     pool, bt, tok, pos = state
-    names = ["k", "v"] + (["k_scale", "v_scale"] if plan.kv_bits else [])
     xla = plan.replace(kernel_mode="xla")
     cfg32 = cfg.replace(compute_dtype="float32")
     f_pal, f_xla, f_ref = (_layer_fn(cfg, plan), _layer_fn(cfg, xla),
@@ -264,17 +262,18 @@ def _layer_sync(packed, dense, table, cfg, plan, state):
         upd = norm(d - base)
         rows.append((norm(a - d) / upd, norm(d - t) / upd))
 
+    pool32 = jax.tree_util.tree_map(f32, pool)
     for l in range(cfg.n_layers):
-        pool_l = [pool[n][l] for n in names]
+        li = jnp.int32(l)
         y_pal = f_pal(jax.tree_util.tree_map(lambda a: a[l],
                                              packed["layers"]),
-                      x, bt, pos, *pool_l)
+                      x, bt, pos, pool, li)
         y_xla = f_xla(jax.tree_util.tree_map(lambda a: a[l],
                                              dense["layers"]),
-                      x, bt, pos, *pool_l)
+                      x, bt, pos, pool, li)
         with jax.default_matmul_precision("highest"):
             y_ref = f_ref(dequantize_tree(table[str(l)]), f32(x), bt, pos,
-                          *[f32(p) for p in pool_l])
+                          pool32, li)
         _check(_finite(y_pal) and _finite(y_xla) and _finite(y_ref),
                f"layer {l}: non-finite activations")
         gate(y_pal, y_xla, y_ref, x)

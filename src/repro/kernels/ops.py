@@ -89,40 +89,53 @@ def comq_panel_dq(h_bb: Array, s0: Array, qf: Array, delta: Array,
                                 interpret=(mode == "interpret"))
 
 
+def _heads(rows: Array, n_kv: int) -> Array:
+    """(..., KV·w) lane-dense page rows -> (..., KV, w)."""
+    return rows.reshape(*rows.shape[:-1], n_kv, rows.shape[-1] // n_kv)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "mode"))
-def paged_attention(q: Array, k_pool: Array, v_pool: Array,
+def paged_attention(q: Array, k_pool: Array, v_pool: Array, layer: Array,
                     block_tables: Array, lengths: Array, *,
                     window: int = 0, mode: Optional[str] = None) -> Array:
     """Decode attention over a paged KV pool (serve/kv_cache.py layout):
-    q (B, Hp, hd) single query token per slot; block_tables (B, MAXB)
-    physical page ids; lengths (B,) valid tokens (0 = inactive slot)."""
+    q (B, Hp, hd) single query token per slot; k_pool/v_pool the layer-
+    stacked, lane-dense (L, NB, BS, KV·hd) pages, read at layer `layer`
+    (int32 scalar); block_tables (B, MAXB) physical page ids; lengths (B,)
+    valid tokens (0 = inactive slot)."""
     mode = resolve_mode(mode)
     if mode == "xla":
-        return ref.paged_attention_ref(q, k_pool, v_pool, block_tables,
-                                       lengths, window=window).astype(q.dtype)
-    return paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
-                                  window=window,
+        n_kv = k_pool.shape[-1] // q.shape[-1]
+        return ref.paged_attention_ref(
+            q, _heads(k_pool[layer], n_kv), _heads(v_pool[layer], n_kv),
+            block_tables, lengths, window=window).astype(q.dtype)
+    return paged_attention_pallas(q, k_pool, v_pool, layer, block_tables,
+                                  lengths, window=window,
                                   interpret=(mode == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "kv_bits", "mode"))
 def paged_attention_quant(q: Array, k_pool: Array, v_pool: Array,
-                          k_scale: Array, v_scale: Array,
+                          k_scale: Array, v_scale: Array, layer: Array,
                           block_tables: Array, lengths: Array, *,
                           window: int = 0, kv_bits: int = 8,
                           mode: Optional[str] = None) -> Array:
     """Decode attention over a *quantized* paged pool: k_pool/v_pool hold
-    integer codes (int8 / packed 4-bit) and k_scale/v_scale (NB, KV) the
-    per-(page, kv_head) scales. The Pallas path streams codes and folds
-    the scales inside the kernel; `xla` takes the dequantizing oracle."""
+    layer-stacked, lane-dense integer codes (L, NB, BS, KV·hd/cpb; int8 /
+    packed 4-bit) and k_scale/v_scale (L, NB, KV) the per-(layer, page,
+    kv_head) scales, read at layer `layer`. The Pallas path streams codes
+    and folds the scales inside the kernel; `xla` takes the dequantizing
+    oracle."""
     mode = resolve_mode(mode)
     if mode == "xla":
+        n_kv = k_scale.shape[-1]
         return ref.paged_attention_quant_ref(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths,
+            q, _heads(k_pool[layer], n_kv), _heads(v_pool[layer], n_kv),
+            k_scale[layer], v_scale[layer], block_tables, lengths,
             window=window, kv_bits=kv_bits).astype(q.dtype)
     return paged_attention_quant_pallas(q, k_pool, v_pool, k_scale, v_scale,
-                                        block_tables, lengths, window=window,
-                                        kv_bits=kv_bits,
+                                        layer, block_tables, lengths,
+                                        window=window, kv_bits=kv_bits,
                                         interpret=(mode == "interpret"))
 
 

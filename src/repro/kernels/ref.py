@@ -35,7 +35,9 @@ def quant_matmul_packed_ref(x: Array, codes_p: Array, scale: Array,
 def paged_attention_ref(q: Array, k_pool: Array, v_pool: Array,
                         block_tables: Array, lengths: Array, *,
                         window: int = 0) -> Array:
-    """q: (B, H, hd); k_pool/v_pool: (NB, BS, KV, hd); block_tables:
+    """q: (B, H, hd); k_pool/v_pool: (NB, BS, KV, hd), one layer's pages
+    with the heads split out (`ops.paged_attention` hands it `pool[layer]`
+    so); block_tables:
     (B, MAXB); lengths: (B,). Pure-XLA oracle: gather the slot's pages into
     a contiguous (B, MAXB·BS, KV, hd) view, then masked softmax attention.
     Inactive slots (length 0) return exact zeros, matching the kernel."""

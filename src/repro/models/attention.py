@@ -456,78 +456,97 @@ def decode_attend(q: Array, cache: KVCache, head_map: Array, *,
 
 # ---------------------------------------------------------------------------
 # paged KV cache (serve/kv_cache.py owns the pool + block tables; these are
-# the per-layer device ops the decode scan body runs)
+# the per-layer device ops the decode scan body runs). Every op takes the
+# whole layer-stacked, lane-dense pool (L, NB, BS, KV·hd) and the layer
+# index, and reads or writes that layer's pages in place: the pool rides
+# the layer scan's carry, so no op ever slices out or writes back a whole
+# layer.
 # ---------------------------------------------------------------------------
 
 def paged_insert(k_pool: Array, v_pool: Array, k_new: Array, v_new: Array,
-                 block_tables: Array, pos: Array):
-    """Write one token per slot into the paged pool.
+                 layer: Array, block_tables: Array, pos: Array):
+    """Write one token per slot into layer `layer` of the paged pool.
 
-    k_pool/v_pool: (NB, BS, KV, hd); k_new/v_new: (B, 1, KV, hd);
+    k_pool/v_pool: (L, NB, BS, KV·hd); k_new/v_new: (B, 1, KV, hd);
     block_tables: (B, MAXB) physical block ids; pos: (B,) absolute write
     position, -1 = inactive slot (write dropped). Slots own disjoint blocks
     so the B scattered rows never collide."""
-    NB, BS = k_pool.shape[0], k_pool.shape[1]
+    NB, BS = k_pool.shape[1], k_pool.shape[2]
+    B = pos.shape[0]
     safe = jnp.maximum(pos, 0)
     phys = jnp.take_along_axis(block_tables, (safe // BS)[:, None],
                                axis=1)[:, 0]
-    dest = jnp.where(pos >= 0, phys * BS + safe % BS, NB * BS)  # OOB -> drop
-    kf = k_pool.reshape(NB * BS, *k_pool.shape[2:])
-    vf = v_pool.reshape(NB * BS, *v_pool.shape[2:])
-    kf = kf.at[dest].set(k_new[:, 0].astype(kf.dtype), mode="drop")
-    vf = vf.at[dest].set(v_new[:, 0].astype(vf.dtype), mode="drop")
-    return kf.reshape(k_pool.shape), vf.reshape(v_pool.shape)
+    page = jnp.where(pos >= 0, phys, NB)                 # OOB -> drop
+    off = safe % BS
+    k_pool = k_pool.at[layer, page, off].set(
+        k_new.reshape(B, -1).astype(k_pool.dtype), mode="drop")
+    v_pool = v_pool.at[layer, page, off].set(
+        v_new.reshape(B, -1).astype(v_pool.dtype), mode="drop")
+    return k_pool, v_pool
 
 
-def paged_gather(pool: Array, block_tables: Array) -> Array:
-    """(NB, BS, KV, hd) + (B, MAXB) -> (B, MAXB·BS, KV, hd): a slot's pages
-    in logical order (row i holds position i)."""
-    NB, BS = pool.shape[0], pool.shape[1]
+def paged_gather(pool: Array, layer: Array, block_tables: Array,
+                 n_kv: int) -> Array:
+    """(L, NB, BS, KV·w) + layer + (B, MAXB) -> (B, MAXB·BS, KV, w): a
+    slot's pages of that layer in logical order (row i holds position i),
+    heads split out."""
     B, MAXB = block_tables.shape
-    idx = (block_tables[:, :, None] * BS
-           + jnp.arange(BS, dtype=jnp.int32)[None, None])
-    return pool.reshape(NB * BS, *pool.shape[2:])[idx.reshape(B, MAXB * BS)]
+    BS = pool.shape[2]
+    pages = jnp.repeat(block_tables, BS, axis=1)          # (B, MAXB·BS)
+    offs = jnp.tile(jnp.arange(BS, dtype=jnp.int32), MAXB)[None]
+    rows = pool[layer, pages, offs]
+    return rows.reshape(B, MAXB * BS, n_kv, -1)
 
 
-def _check_grouped(q: Array, k_pool: Array) -> None:
+def _check_grouped(q: Array, k_pool: Array, n_kv: int) -> None:
     """The Pallas paged kernels need grouped GQA; a non-grouped head
     layout is refused, never routed silently to the XLA gather (the
     paged runtime serves grouped archs only — launch/serve.py sends the
     rest to the static engine)."""
-    H, KV = q.shape[2], k_pool.shape[2]
-    if H % KV:
+    H = q.shape[2]
+    if H % n_kv:
         raise ValueError(
             f"paged decode kernel needs grouped GQA: q {tuple(q.shape)} has "
-            f"{H} heads over k_pool {tuple(k_pool.shape)} with {KV} kv "
+            f"{H} heads over k_pool {tuple(k_pool.shape)} with {n_kv} kv "
             "heads; pass mode='xla' explicitly for the gather path")
 
 
-def paged_decode_attend(q: Array, k_pool: Array, v_pool: Array,
-                        block_tables: Array, lengths: Array,
-                        head_map: Array, *, window: int = 0,
-                        mode: Optional[str] = None) -> Array:
-    """q: (B, 1, Hp, hd); lengths: (B,) valid tokens per slot (0 inactive).
-
-    Backend dispatch mirrors kernels/ops.py: on TPU (or with an explicit
-    mode="interpret") the Pallas paged kernel DMAs pages via scalar-
-    prefetched block tables; the XLA path (the CPU default, or an explicit
-    mode="xla") gathers the slot's pages into logical order and
-    runs the same `_dense_attention` the dense decode path uses — so paged
-    and dense decode agree bitwise for equal cache extents."""
-    from repro.kernels.ops import resolve_mode
-    mode = resolve_mode(mode)
-    if mode != "xla":
-        _check_grouped(q, k_pool)
-        from repro.kernels import ops
-        o = ops.paged_attention(q[:, 0], k_pool, v_pool, block_tables,
-                                lengths, window=window, mode=mode)
-        return o[:, None].astype(q.dtype)
-    kg = paged_gather(k_pool, block_tables).astype(q.dtype)
-    vg = paged_gather(v_pool, block_tables).astype(q.dtype)
-    B, S = kg.shape[0], kg.shape[1]
+def _paged_positions(lengths: Array, S: int):
+    """Key positions, their validity and the query position of a gathered
+    (B, S) paged context."""
+    B = lengths.shape[0]
     kpos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     valid = kpos < lengths[:, None]
     qp = jnp.maximum(lengths - 1, 0)[:, None].astype(jnp.int32)
+    return kpos, valid, qp
+
+
+def paged_decode_attend(q: Array, k_pool: Array, v_pool: Array,
+                        layer: Array, block_tables: Array, lengths: Array,
+                        head_map: Array, *, window: int = 0,
+                        mode: Optional[str] = None) -> Array:
+    """q: (B, 1, Hp, hd); k_pool/v_pool: (L, NB, BS, KV·hd), read at
+    `layer`; lengths: (B,) valid tokens per slot (0 inactive).
+
+    Backend dispatch mirrors kernels/ops.py: on TPU (or with an explicit
+    mode="interpret") the Pallas paged kernel DMAs pages via scalar-
+    prefetched layer index and block tables; the XLA path (the CPU
+    default, or an explicit mode="xla") gathers the slot's pages into
+    logical order and runs the same `_dense_attention` the dense decode
+    path uses — so paged and dense decode agree bitwise for equal cache
+    extents."""
+    from repro.kernels.ops import resolve_mode
+    mode = resolve_mode(mode)
+    n_kv = k_pool.shape[-1] // q.shape[-1]
+    if mode != "xla":
+        _check_grouped(q, k_pool, n_kv)
+        from repro.kernels import ops
+        o = ops.paged_attention(q[:, 0], k_pool, v_pool, layer, block_tables,
+                                lengths, window=window, mode=mode)
+        return o[:, None].astype(q.dtype)
+    kg = paged_gather(k_pool, layer, block_tables, n_kv).astype(q.dtype)
+    vg = paged_gather(v_pool, layer, block_tables, n_kv).astype(q.dtype)
+    kpos, valid, qp = _paged_positions(lengths, kg.shape[1])
     return _dense_attention(q, kg, vg, head_map, causal=True, window=window,
                             q_positions=qp, kv_positions=kpos,
                             kv_valid=valid)
@@ -535,12 +554,14 @@ def paged_decode_attend(q: Array, k_pool: Array, v_pool: Array,
 
 def paged_insert_quant(k_pool: Array, v_pool: Array, k_scale: Array,
                        v_scale: Array, k_new: Array, v_new: Array,
-                       block_tables: Array, pos: Array, *, kv_bits: int):
-    """Write one token per slot into a *quantized* pool (decode append).
+                       layer: Array, block_tables: Array, pos: Array, *,
+                       kv_bits: int):
+    """Write one token per slot into layer `layer` of a *quantized* pool
+    (decode append).
 
-    k_pool/v_pool: (NB, BS, KV, hd/cpb) integer codes; k_scale/v_scale:
-    (NB, KV) f32 per-(page, kv_head) scales; k_new/v_new: (B, 1, KV, hd)
-    float; pos: (B,), -1 = inactive (write dropped).
+    k_pool/v_pool: (L, NB, BS, KV·hd/cpb) integer codes; k_scale/v_scale:
+    (L, NB, KV) f32 per-(layer, page, kv_head) scales; k_new/v_new:
+    (B, 1, KV, hd) float; pos: (B,), -1 = inactive (write dropped).
 
     The page scale is a running max: appending a token with a larger
     absmax raises the page scale, and the page's existing codes rescale
@@ -550,8 +571,8 @@ def paged_insert_quant(k_pool: Array, v_pool: Array, k_scale: Array,
     scale/codes belong to a freed request and are overwritten, not
     maxed."""
     from repro.serve.kv_cache import _kv_qmax, kv_encode, kv_scale_of
-    NB, BS = k_pool.shape[0], k_pool.shape[1]
-    B = pos.shape[0]
+    NB, BS = k_pool.shape[1], k_pool.shape[2]
+    B, KV = pos.shape[0], k_scale.shape[-1]
     qmax = _kv_qmax(kv_bits)
     safe = jnp.maximum(pos, 0)
     phys = jnp.take_along_axis(block_tables, (safe // BS)[:, None],
@@ -564,13 +585,13 @@ def paged_insert_quant(k_pool: Array, v_pool: Array, k_scale: Array,
                              (v_pool, v_scale, v_new)):
         row = new[:, 0].astype(jnp.float32)          # (B, KV, hd)
         s_tok = kv_scale_of(jnp.max(jnp.abs(row), axis=-1), kv_bits)
-        old = scale[phys]                            # (B, KV)
+        old = scale[layer, phys]                     # (B, KV)
         s_new = jnp.where(fresh, s_tok, jnp.maximum(old, s_tok))
         # rescale the page's existing codes to the (possibly) raised
         # scale; ratio 0 wipes a fresh page's stale codes outright
         ratio = jnp.where(fresh | (s_new <= 0), 0.0,
                           old / jnp.where(s_new > 0, s_new, 1.0))
-        page = pool[phys]                            # (B, BS, KV, hd/cpb)
+        page = pool[layer, phys].reshape(B, BS, KV, -1)  # (B, BS, KV, w)
         if kv_bits == 8:
             pq = page.astype(jnp.float32) * ratio[:, None, :, None]
             page2 = jnp.clip(jnp.round(pq), -qmax, qmax).astype(jnp.int8)
@@ -583,48 +604,45 @@ def paged_insert_quant(k_pool: Array, v_pool: Array, k_scale: Array,
         tok = kv_encode(row, s_new, kv_bits)         # (B, KV, hd/cpb)
         at_off = jnp.arange(BS)[None, :, None, None] \
             == off[:, None, None, None]
-        page2 = jnp.where(at_off, tok[:, None], page2)
-        out.append(pool.at[dest].set(page2, mode="drop"))
-        out.append(scale.at[dest].set(s_new, mode="drop"))
+        page2 = jnp.where(at_off, tok[:, None], page2).reshape(B, BS, -1)
+        out.append(pool.at[layer, dest].set(page2, mode="drop"))
+        out.append(scale.at[layer, dest].set(s_new, mode="drop"))
     return tuple(out)  # (k_pool, k_scale, v_pool, v_scale)
 
 
 def paged_decode_attend_quant(q: Array, k_pool: Array, v_pool: Array,
-                              k_scale: Array, v_scale: Array,
+                              k_scale: Array, v_scale: Array, layer: Array,
                               block_tables: Array, lengths: Array,
                               head_map: Array, *, window: int = 0,
                               kv_bits: int = 8,
                               mode: Optional[str] = None) -> Array:
-    """Quantized-pool decode attention. Pallas/interpret streams the codes
-    and folds the per-page scales inside the kernel; the XLA fallback
-    gathers codes + per-row scales, dequantizes, and runs the same
-    `_dense_attention` as the bf16 fallback — elementwise it is exactly
-    the bf16 fallback applied to the dequantized pool."""
+    """Quantized-pool decode attention at layer `layer`. Pallas/interpret
+    streams the codes and folds the per-page scales inside the kernel; the
+    XLA fallback gathers codes + per-row scales, dequantizes, and runs the
+    same `_dense_attention` as the bf16 fallback — elementwise it is
+    exactly the bf16 fallback applied to the dequantized pool."""
     from repro.serve.kv_cache import kv_decode
     from repro.kernels.ops import resolve_mode
     mode = resolve_mode(mode)
+    n_kv = k_scale.shape[-1]
     if mode != "xla":
-        _check_grouped(q, k_pool)
+        _check_grouped(q, k_pool, n_kv)
         from repro.kernels import ops
         o = ops.paged_attention_quant(q[:, 0], k_pool, v_pool, k_scale,
-                                      v_scale, block_tables, lengths,
+                                      v_scale, layer, block_tables, lengths,
                                       window=window, kv_bits=kv_bits,
                                       mode=mode)
         return o[:, None].astype(q.dtype)
-    BS = k_pool.shape[1]
-    B, MAXB = block_tables.shape
+    BS = k_pool.shape[2]
     # per-row scales in logical order: page scale repeated over the page
-    ks_rows = jnp.repeat(k_scale[block_tables], BS,
+    ks_rows = jnp.repeat(k_scale[layer, block_tables], BS,
                          axis=1)                     # (B, MAXB*BS, KV)
-    vs_rows = jnp.repeat(v_scale[block_tables], BS, axis=1)
-    kg = kv_decode(paged_gather(k_pool, block_tables), ks_rows, kv_bits,
-                   dtype=q.dtype)
-    vg = kv_decode(paged_gather(v_pool, block_tables), vs_rows, kv_bits,
-                   dtype=q.dtype)
-    S = kg.shape[1]
-    kpos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    valid = kpos < lengths[:, None]
-    qp = jnp.maximum(lengths - 1, 0)[:, None].astype(jnp.int32)
+    vs_rows = jnp.repeat(v_scale[layer, block_tables], BS, axis=1)
+    kg = kv_decode(paged_gather(k_pool, layer, block_tables, n_kv), ks_rows,
+                   kv_bits, dtype=q.dtype)
+    vg = kv_decode(paged_gather(v_pool, layer, block_tables, n_kv), vs_rows,
+                   kv_bits, dtype=q.dtype)
+    kpos, valid, qp = _paged_positions(lengths, kg.shape[1])
     return _dense_attention(q, kg, vg, head_map, causal=True, window=window,
                             q_positions=qp, kv_positions=kpos,
                             kv_valid=valid)

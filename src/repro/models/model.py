@@ -128,9 +128,10 @@ def scan_layers(body, x, layers, *per_layer_xs):
     historical path, byte-identical) or a `core.apply.SegmentedLayers`
     (mixed-bit serving_params): then one scan runs per homogeneous
     segment, each over its slice of the per-layer operands (KV caches,
-    paged pools, states), and the stacked ys re-concatenate along the
-    layer axis — so a mixed 4/8-bit tree keeps every segment's codes
-    packed at their own width inside its own compiled scan."""
+    states, layer indices), the carry passing from one segment's scan to
+    the next, and the stacked ys re-concatenate along the layer axis — so
+    a mixed 4/8-bit tree keeps every segment's codes packed at their own
+    width inside its own compiled scan."""
     from repro.core.apply import is_segmented
     if not is_segmented(layers):
         return jax.lax.scan(body, x, (layers, *per_layer_xs))
@@ -413,8 +414,16 @@ def decode_step_paged(p: Params, cfg, plan: BuildPlan, pool, block_tables,
 
     tokens: (B, 1) int32; pos: (B,) int32 absolute write positions per slot
     (-1 = inactive slot: K/V write dropped, logits garbage the runtime
-    masks); pool: {"k","v"} of (L, NB, BS, KV, hd) pages (serve/kv_cache);
+    masks); pool: {"k","v"} of (L, NB, BS, KV·hd) lane-dense pages, plus
+    {"k_scale","v_scale"} (L, NB, KV) for quantized pages (serve/kv_cache);
     block_tables: (B, MAXB) physical page ids.
+
+    The pool rides the layer scan's carry and each layer reads and writes
+    its pages in place by its global layer index (the scan's xs carry
+    `arange(L)` beside the layer params; a `SegmentedLayers` tree gives
+    each segment its own range of it) — no layer's pool is sliced out or
+    written back, so with the pool donated the step moves only the bytes
+    it attends over and appends (DESIGN.md §11.4).
 
     Unlike `decode_step`, every slot carries its own position — a mixed-
     length, staggered-arrival batch decodes in one jitted program. Covers
@@ -428,35 +437,21 @@ def decode_step_paged(p: Params, cfg, plan: BuildPlan, pool, block_tables,
     x = embed_tokens(p, cfg, plan, tokens)
     cd = dtype_of(cfg.compute_dtype)
 
-    if plan.kv_bits:
-        # quantized pool: per-(layer, page, kv_head) scales ride the scan
-        # as two extra per-layer operands (DESIGN.md §11)
-        def body(x, xs):
-            lp, kl, vl, ksl, vsl = xs
-            lp = dequantize_qt_tree(lp, cd, keep_fused=True)
-            x, kl, vl, ksl, vsl = tfm.layer_decode_paged(
-                lp, x, cfg, plan, kl, vl, block_tables, pos, ksl, vsl)
-            return plan.constrain(x, "residual"), (kl, vl, ksl, vsl)
+    def body(carry, xs):
+        x, pool = carry
+        lp, layer = xs
+        lp = dequantize_qt_tree(lp, cd, keep_fused=True)
+        x, pool = tfm.layer_decode_paged(lp, x, cfg, plan, pool, layer,
+                                         block_tables, pos)
+        return (plan.constrain(x, "residual"), pool), None
 
-        x, (nk, nv, nks, nvs) = scan_layers(
-            body, x, p["layers"], pool["k"], pool["v"],
-            pool["k_scale"], pool["v_scale"])
-        new_pool = {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
-    else:
-        def body(x, xs):
-            lp, kl, vl = xs
-            lp = dequantize_qt_tree(lp, cd, keep_fused=True)
-            x, kl, vl = tfm.layer_decode_paged(lp, x, cfg, plan, kl, vl,
-                                               block_tables, pos)
-            return plan.constrain(x, "residual"), (kl, vl)
-
-        x, (nk, nv) = scan_layers(body, x, p["layers"], pool["k"],
-                                  pool["v"])
-        new_pool = {"k": nk, "v": nv}
+    n_layers = pool["k"].shape[0]
+    (x, pool), _ = scan_layers(body, (x, pool), p["layers"],
+                               jnp.arange(n_layers, dtype=jnp.int32))
     from repro.models.common import apply_norm
     x = apply_norm(p["final_norm"], x, cfg)
     logits = unembed(p, cfg, plan, x)
-    return logits[:, 0], new_pool
+    return logits[:, 0], pool
 
 
 # ---------------------------------------------------------------------------

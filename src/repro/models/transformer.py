@@ -316,23 +316,24 @@ def _decode_ffn(p: dict, x: Array, cfg, plan: BuildPlan) -> Array:
 
 
 def layer_decode_paged(p: dict, x: Array, cfg, plan: BuildPlan,
-                       k_pool: Array, v_pool: Array, block_tables: Array,
-                       pos: Array, k_scale: Optional[Array] = None,
-                       v_scale: Optional[Array] = None):
-    """One decode step against the paged KV pool (serve/kv_cache.py).
+                       pool: dict, layer: Array, block_tables: Array,
+                       pos: Array):
+    """One decode step of layer `layer` against the paged KV pool
+    (serve/kv_cache.py).
 
-    x: (B, 1, d); k_pool/v_pool: this layer's (NB, BS, KV, hd) pages;
-    block_tables: (B, MAXB) physical page ids per slot; pos: (B,) absolute
-    write position per slot, -1 = inactive (write dropped, output garbage
-    that the runtime masks). Unlike `layer_decode`, positions are per-slot
-    vectors — slots sit at different sequence lengths (continuous batching).
-    Returns (x, k_pool, v_pool).
+    x: (B, 1, d); pool: the whole layer-stacked pool, {"k", "v"} of
+    lane-dense (L, NB, BS, KV·hd) pages; layer: int32 index of this layer in the
+    stack; block_tables: (B, MAXB) physical page ids per slot; pos: (B,)
+    absolute write position per slot, -1 = inactive (write dropped,
+    output garbage that the runtime masks). Unlike `layer_decode`,
+    positions are per-slot vectors — slots sit at different sequence
+    lengths (continuous batching). The append and the attention read and
+    write layer `layer` of the stacked pool in place. Returns (x, pool).
 
-    With `plan.kv_bits` set the pools hold integer codes and
-    k_scale/v_scale (NB, KV) carry the per-(page, kv_head) scales: the
-    append re-quantizes under a running-max page scale and attention
-    dequantizes in-kernel (or in the gather fallback). Returns
-    (x, k_pool, v_pool, k_scale, v_scale) in that case."""
+    With `plan.kv_bits` set the pool holds integer codes and
+    "k_scale"/"v_scale" (L, NB, KV) carry the per-(layer, page, kv_head)
+    scales: the append re-quantizes under a running-max page scale and
+    attention dequantizes in-kernel (or in the gather fallback)."""
     hp = plan.heads_padded(cfg)
     hmap = head_to_kv_map(cfg.n_heads, hp, cfg.n_kv_heads)
     xn = apply_norm(p["ln1"], x, cfg)
@@ -342,19 +343,21 @@ def layer_decode_paged(p: dict, x: Array, cfg, plan: BuildPlan,
     k = apply_rope(k, posb, cfg.rope_theta)
     lengths = jnp.maximum(pos + 1, 0)
     if plan.kv_bits:
-        k_pool, k_scale, v_pool, v_scale = attn_mod.paged_insert_quant(
-            k_pool, v_pool, k_scale, v_scale, k, v, block_tables, pos,
-            kv_bits=plan.kv_bits)
+        kp, ks, vp, vs = attn_mod.paged_insert_quant(
+            pool["k"], pool["v"], pool["k_scale"], pool["v_scale"], k, v,
+            layer, block_tables, pos, kv_bits=plan.kv_bits)
+        pool = {"k": kp, "v": vp, "k_scale": ks, "v_scale": vs}
         o = attn_mod.paged_decode_attend_quant(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths,
-            hmap, window=cfg.sliding_window, kv_bits=plan.kv_bits,
+            q, kp, vp, ks, vs, layer, block_tables, lengths, hmap,
+            window=cfg.sliding_window, kv_bits=plan.kv_bits,
             mode=plan.kernel_mode)
-        x = x + attn_mod.out_project(p["attn"], o)
-        x = x + _decode_ffn(p, x, cfg, plan)
-        return x, k_pool, v_pool, k_scale, v_scale
-    k_pool, v_pool = paged_insert(k_pool, v_pool, k, v, block_tables, pos)
-    o = paged_decode_attend(q, k_pool, v_pool, block_tables, lengths, hmap,
-                            window=cfg.sliding_window, mode=plan.kernel_mode)
+    else:
+        kp, vp = paged_insert(pool["k"], pool["v"], k, v, layer,
+                              block_tables, pos)
+        pool = {"k": kp, "v": vp}
+        o = paged_decode_attend(q, kp, vp, layer, block_tables, lengths,
+                                hmap, window=cfg.sliding_window,
+                                mode=plan.kernel_mode)
     x = x + attn_mod.out_project(p["attn"], o)
     x = x + _decode_ffn(p, x, cfg, plan)
-    return x, k_pool, v_pool
+    return x, pool

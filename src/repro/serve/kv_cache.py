@@ -9,11 +9,21 @@ table (kernels/paged_attention.py Pallas kernel on TPU, gather fallback on
 XLA — models/attention.paged_decode_attend). Memory scales with the
 *live* tokens, not max_slots x max_len.
 
-Device layout (models/model.decode_step_paged scans layers over the pool):
+Device layout (models/model.decode_step_paged carries the whole pool
+through its layer scan and each layer reads and writes its own pages in
+place):
 
-    pool["k"], pool["v"]: (L, num_blocks, block_size, KV, hd)
+    pool["k"], pool["v"]: (L, num_blocks, block_size, KV·hd)   lane-dense
     block_tables:         (max_slots, max_blocks_per_slot) int32
     pos:                  (max_slots,) absolute next position, -1 inactive
+
+A page row holds the token's KV heads side by side (head j on lanes
+[j·hd, (j+1)·hd)). Lane-dense rows are what lets the pool stay in place:
+a TPU lays out an array whose minor dim is a multiple of 128 row-major,
+the layout the paged attention kernels read, while a (…, KV, hd) pool
+with head_dim off the lane grid (danube's 80) gets the page-id dim
+minor-most and every program would relayout the whole pool around the
+kernel (DESIGN.md §11.4).
 
 `BlockAllocator` is plain host state (the scheduler thread owns it); the
 jitted `write_prefill` scatters a prefilled dense cache's rows into the
@@ -89,20 +99,22 @@ def kv_decode(codes: Array, scale: Array, kv_bits: int,
 
 def init_paged_cache(cfg, plan, num_blocks: int,
                      block_size: int) -> Dict[str, Array]:
-    """Zeroed K/V page pools, stacked over layers for the decode scan.
-    With `plan.kv_bits` in {4, 8} pages hold integer codes plus per-
+    """Zeroed K/V page pools, stacked over layers for the decode scan, one
+    lane-dense row of KV·hd values (or codes) per token. With
+    `plan.kv_bits` in {4, 8} pages hold integer codes plus per-
     (layer, page, kv_head) f32 scales under "k_scale"/"v_scale"."""
     hd = cfg.resolved_head_dim
     kv_bits = int(getattr(plan, "kv_bits", 0) or 0)
     if not kv_bits:
-        shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads, hd)
+        shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads * hd)
         return {"k": jnp.zeros(shape, plan.cache_dtype),
                 "v": jnp.zeros(shape, plan.cache_dtype)}
     cpb = kv_code_width(kv_bits)
     if hd % cpb:
         raise ValueError(f"kv_bits={kv_bits} needs head_dim % {cpb} == 0")
     dt = jnp.int8 if kv_bits == 8 else jnp.uint8
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads, hd // cpb)
+    shape = (cfg.n_layers, num_blocks, block_size,
+             cfg.n_kv_heads * (hd // cpb))
     sshape = (cfg.n_layers, num_blocks, cfg.n_kv_heads)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
             "k_scale": jnp.zeros(sshape, jnp.float32),
@@ -221,9 +233,9 @@ def write_prefill(pool: Dict[str, Array], k_seq: Array, v_seq: Array,
                   kv_bits: int = 0) -> Dict[str, Array]:
     """Scatter one request's prefilled K/V rows into its pages.
 
-    k_seq/v_seq: (L, S, KV, hd) from the dense prefill cache; pos_row: (S,)
-    absolute positions (-1 = unwritten row, dropped); table_row: (MAXB,)
-    physical page ids. Rows route by position — block pos//BS, offset
+    k_seq/v_seq: (L, S, KV, hd) from the dense prefill cache, written as
+    lane-dense KV·hd rows; pos_row: (S,) absolute positions (-1 = unwritten
+    row, dropped); table_row: (MAXB,) physical page ids. Rows route by position — block pos//BS, offset
     pos%BS — so ring-buffer (SWA) prefill caches scatter correctly.
 
     With `kv_bits` set the rows quantize on the way in: every touched page
@@ -238,18 +250,24 @@ def write_prefill(pool: Dict[str, Array], k_seq: Array, v_seq: Array,
     valid = pos_row >= 0
     phys = table_row[safe // BS]
     dest = jnp.where(valid, phys * BS + safe % BS, NB * BS)
+    S = pos_row.shape[0]
+    # every (layer, row) pair is its own scatter index, so the scatter's
+    # window is one lane-dense row and the pool keeps its row-major layout
+    at = (jnp.arange(L)[:, None], dest[None, :])
     if not kv_bits:
-        kf = k_pool.reshape(L, NB * BS, *k_pool.shape[3:])
-        vf = v_pool.reshape(L, NB * BS, *v_pool.shape[3:])
-        kf = kf.at[:, dest].set(k_seq.astype(kf.dtype), mode="drop")
-        vf = vf.at[:, dest].set(v_seq.astype(vf.dtype), mode="drop")
+        kf = k_pool.reshape(L, NB * BS, k_pool.shape[3])
+        vf = v_pool.reshape(L, NB * BS, v_pool.shape[3])
+        kf = kf.at[at].set(k_seq.reshape(L, S, -1).astype(kf.dtype),
+                           mode="drop")
+        vf = vf.at[at].set(v_seq.reshape(L, S, -1).astype(vf.dtype),
+                           mode="drop")
         return {"k": kf.reshape(k_pool.shape), "v": vf.reshape(v_pool.shape)}
 
     page = jnp.where(valid, phys, NB)           # OOB sentinel -> drop
     touched = jnp.zeros((NB,), bool).at[page].set(True, mode="drop")
     out = {}
     for name, cpool, rows in (("k", k_pool, k_seq), ("v", v_pool, v_seq)):
-        KV = cpool.shape[3]
+        KV = rows.shape[2]
         absmax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=-1)
         absmax = jnp.where(valid[None, :, None], absmax, 0.0)   # (L, S, KV)
         pmax = jnp.zeros((L, NB, KV), jnp.float32) \
@@ -258,8 +276,8 @@ def write_prefill(pool: Dict[str, Array], k_seq: Array, v_seq: Array,
                               kv_scale_of(pmax, kv_bits),
                               pool[name + "_scale"])
         codes = kv_encode(rows, new_scale[:, page], kv_bits)
-        cf = cpool.reshape(L, NB * BS, *cpool.shape[3:])
-        cf = cf.at[:, dest].set(codes, mode="drop")
+        cf = cpool.reshape(L, NB * BS, cpool.shape[3])
+        cf = cf.at[at].set(codes.reshape(L, S, -1), mode="drop")
         out[name] = cf.reshape(cpool.shape)
         out[name + "_scale"] = new_scale
     return out

@@ -80,6 +80,25 @@ def _quant_pool(key, NB, BS, KV, hd, kv_bits):
     return kq, vq, ks, vs
 
 
+def _lane_dense(pool):
+    """(..., KV, w) -> the runtime's lane-dense (..., KV·w) rows."""
+    return pool.reshape(*pool.shape[:-2], -1)
+
+
+def _heads(rows, n_kv):
+    """Lane-dense (..., KV·w) rows -> (..., KV, w)."""
+    return rows.reshape(*rows.shape[:-1], n_kv, -1)
+
+
+def _quant_pool_stack(key, L, NB, BS, KV, hd, kv_bits):
+    """L independent `_quant_pool` layers stacked as the runtime stores
+    them: lane-dense codes (L, NB, BS, KV·hd/cpb), scales (L, NB, KV)."""
+    layers = [_quant_pool(k, NB, BS, KV, hd, kv_bits)
+              for k in jax.random.split(key, L)]
+    kq, vq, ks, vs = (jnp.stack(parts) for parts in zip(*layers))
+    return _lane_dense(kq), _lane_dense(vq), ks, vs
+
+
 @pytest.mark.parametrize("kv_bits", [8, 4])
 def test_quant_ref_equals_bf16_ref_on_dequantized_pool(kv_bits):
     """The quantized oracle IS the bf16 oracle applied to the dequantized
@@ -108,28 +127,66 @@ def test_quant_ref_equals_bf16_ref_on_dequantized_pool(kv_bits):
                                          (8, 2, 9)])   # GQA + SWA
 def test_quant_fallback_and_kernel_match_ref(kv_bits, H, KV, window):
     """XLA gather fallback and the interpret-mode Pallas kernel (per-page
-    scales folded into online softmax) both match the dequantizing oracle.
-    The kernel matches everywhere incl. inactive slots (exact zeros); the
-    fallback's dense attend is only defined on active slots."""
-    NB, BS, hd, B, MAXB = 10, 8, 32, 3, 4
-    kq, vq, ks, vs = _quant_pool(KEY, NB, BS, KV, hd, kv_bits)
+    scales folded into online softmax) both match the dequantizing oracle
+    on the layer they are given of a layer-stacked pool. The kernel
+    matches everywhere incl. inactive slots (exact zeros); the fallback's
+    dense attend is only defined on active slots."""
+    L, NB, BS, hd, B, MAXB = 3, 10, 8, 32, 3, 4
+    kq, vq, ks, vs = _quant_pool_stack(KEY, L, NB, BS, KV, hd, kv_bits)
     q1 = jax.random.normal(jax.random.PRNGKey(2), (B, 1, H, hd), jnp.float32)
     bt = jnp.asarray(np.random.RandomState(0).randint(0, NB, (B, MAXB)),
                      jnp.int32)
     lengths = jnp.asarray([17, 0, 32], jnp.int32)
     hm = head_to_kv_map(H, H, KV)
+    layer = jnp.int32(2)
     want = np.asarray(paged_attention_quant_ref(
-        q1[:, 0], kq, vq, ks, vs, bt, lengths, window=window,
-        kv_bits=kv_bits))
+        q1[:, 0], _heads(kq[2], KV), _heads(vq[2], KV), ks[2], vs[2], bt,
+        lengths, window=window, kv_bits=kv_bits))
     got_x = np.asarray(paged_decode_attend_quant(
-        q1, kq, vq, ks, vs, bt, lengths, hm, window=window,
+        q1, kq, vq, ks, vs, layer, bt, lengths, hm, window=window,
         kv_bits=kv_bits, mode="xla"))[:, 0]
     act = np.asarray(lengths) > 0
     np.testing.assert_allclose(got_x[act], want[act], rtol=2e-5, atol=2e-5)
     got_p = np.asarray(paged_decode_attend_quant(
-        q1, kq, vq, ks, vs, bt, lengths, hm, window=window,
+        q1, kq, vq, ks, vs, layer, bt, lengths, hm, window=window,
         kv_bits=kv_bits, mode="interpret"))[:, 0]
     np.testing.assert_allclose(got_p, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv_bits", [0, 8, 4])
+@pytest.mark.parametrize("layer", [1, 3])
+def test_paged_kernels_read_the_layer_they_are_given(kv_bits, layer):
+    """Both Pallas kernels (interpret mode), handed the whole layer-stacked
+    pool and a layer index l != 0, match the XLA gather path on pool[l]
+    for bf16, int8 and 4-bit pages — and not the path on another layer, so
+    an index map that reads the wrong layer fails here."""
+    from repro.models.attention import paged_decode_attend
+    L, NB, BS, KV, H, hd, B, MAXB = 4, 10, 8, 2, 8, 32, 3, 4
+    q1 = jax.random.normal(jax.random.PRNGKey(5), (B, 1, H, hd), jnp.float32)
+    bt = jnp.asarray(np.random.RandomState(1).randint(0, NB, (B, MAXB)),
+                     jnp.int32)
+    lengths = jnp.asarray([19, 32, 5], jnp.int32)
+    hm = head_to_kv_map(H, H, KV)
+    if kv_bits:
+        pool = _quant_pool_stack(KEY, L, NB, BS, KV, hd, kv_bits)
+
+        def attend(l, mode):
+            return paged_decode_attend_quant(
+                q1, *pool, jnp.int32(l), bt, lengths, hm, kv_bits=kv_bits,
+                mode=mode)
+    else:
+        kk, kv_ = jax.random.split(KEY)
+        pool = (jax.random.normal(kk, (L, NB, BS, KV * hd), jnp.float32),
+                jax.random.normal(kv_, (L, NB, BS, KV * hd), jnp.float32))
+
+        def attend(l, mode):
+            return paged_decode_attend(q1, *pool, jnp.int32(l), bt, lengths,
+                                       hm, mode=mode)
+    got = np.asarray(attend(layer, "interpret"))
+    want = np.asarray(attend(layer, "xla"))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    other = np.asarray(attend(layer - 1, "xla"))
+    assert np.max(np.abs(got - other)) > 1e-2
 
 
 # ---------------------------------------------------------------------------
@@ -141,8 +198,8 @@ def test_write_prefill_quantizes_and_wipes_stale_scales(kv_bits):
     L, NB, BS, KV, hd, S, MAXB = 2, 6, 4, 2, 8, 10, 3
     cpb = 1 if kv_bits == 8 else 2
     dt = jnp.int8 if kv_bits == 8 else jnp.uint8
-    pool = {"k": jnp.zeros((L, NB, BS, KV, hd // cpb), dt),
-            "v": jnp.zeros((L, NB, BS, KV, hd // cpb), dt),
+    pool = {"k": jnp.zeros((L, NB, BS, KV * (hd // cpb)), dt),
+            "v": jnp.zeros((L, NB, BS, KV * (hd // cpb)), dt),
             "k_scale": jnp.zeros((L, NB, KV), jnp.float32),
             "v_scale": jnp.zeros((L, NB, KV), jnp.float32)}
     # poison a page this request will reuse: prefill must overwrite its
@@ -159,7 +216,7 @@ def test_write_prefill_quantizes_and_wipes_stale_scales(kv_bits):
     assert not np.any(np.asarray(out["k"][:, 1]))
     # reconstruction within the code width at each page's scale
     for name, seq in (("k", k_seq), ("v", v_seq)):
-        rows = kv_decode(out[name].transpose(0, 1, 3, 2, 4),
+        rows = kv_decode(_heads(out[name], KV).transpose(0, 1, 3, 2, 4),
                          out[name + "_scale"][:, :, :, None],
                          kv_bits).transpose(0, 1, 3, 2, 4)
         for s in range(S):
@@ -174,12 +231,15 @@ def test_write_prefill_quantizes_and_wipes_stale_scales(kv_bits):
 
 @pytest.mark.parametrize("kv_bits", [8, 4])
 def test_paged_insert_quant_running_max_and_fresh_reset(kv_bits):
-    NB, BS, KV, hd, B, MAXB = 6, 4, 2, 8, 2, 3
-    kq = jnp.zeros((NB, BS, KV, hd // (1 if kv_bits == 8 else 2)),
+    """Decode append into layer 1 of a two-layer pool; layer 0 is never
+    touched."""
+    L, NB, BS, KV, hd, B, MAXB = 2, 6, 4, 2, 8, 2, 3
+    kq = jnp.zeros((L, NB, BS, KV * (hd // (1 if kv_bits == 8 else 2))),
                    jnp.int8 if kv_bits == 8 else jnp.uint8)
-    ks = jnp.zeros((NB, KV), jnp.float32)
+    ks = jnp.zeros((L, NB, KV), jnp.float32)
     bt = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     rs = np.random.RandomState(4)
+    layer = jnp.int32(1)
 
     def tok(scale):
         return jnp.asarray(rs.normal(scale=scale, size=(B, 1, KV, hd)),
@@ -188,10 +248,12 @@ def test_paged_insert_quant_running_max_and_fresh_reset(kv_bits):
     # fresh page (off == 0): scale resets to this token's absmax
     t0 = tok(1.0)
     kq1, ks1, vq1, vs1 = paged_insert_quant(
-        kq, kq, ks, ks, t0, t0, bt, jnp.asarray([0, 0], jnp.int32),
+        kq, kq, ks, ks, t0, t0, layer, bt, jnp.asarray([0, 0], jnp.int32),
         kv_bits=kv_bits)
-    back = kv_decode(kq1.transpose(0, 2, 1, 3), ks1[:, :, None],
-                     kv_bits).transpose(0, 2, 1, 3)
+    for a in (kq1, ks1, vq1, vs1):
+        assert not np.any(np.asarray(a[0]))              # layer 0 untouched
+    back = kv_decode(_heads(kq1[1], KV).transpose(0, 2, 1, 3),
+                     ks1[1][:, :, None], kv_bits).transpose(0, 2, 1, 3)
     got = np.asarray(back[np.asarray(bt[:, 0]), 0])
     assert np.max(np.abs(got - np.asarray(t0[:, 0]))) \
         <= 0.51 * float(np.max(np.asarray(ks1))) + 1e-6
@@ -199,40 +261,44 @@ def test_paged_insert_quant_running_max_and_fresh_reset(kv_bits):
     # bounded drift
     t1 = tok(4.0)
     kq2, ks2, _, _ = paged_insert_quant(
-        kq1, vq1, ks1, vs1, t1, t1, bt, jnp.asarray([1, 1], jnp.int32),
-        kv_bits=kv_bits)
-    assert np.all(np.asarray(ks2[np.asarray(bt[:, 0])])
-                  >= np.asarray(ks1[np.asarray(bt[:, 0])]) - 1e-7)
-    back2 = kv_decode(kq2.transpose(0, 2, 1, 3), ks2[:, :, None],
-                      kv_bits).transpose(0, 2, 1, 3)
+        kq1, vq1, ks1, vs1, t1, t1, layer, bt,
+        jnp.asarray([1, 1], jnp.int32), kv_bits=kv_bits)
+    assert np.all(np.asarray(ks2[1][np.asarray(bt[:, 0])])
+                  >= np.asarray(ks1[1][np.asarray(bt[:, 0])]) - 1e-7)
+    back2 = kv_decode(_heads(kq2[1], KV).transpose(0, 2, 1, 3),
+                      ks2[1][:, :, None], kv_bits).transpose(0, 2, 1, 3)
     drift = np.abs(np.asarray(back2[np.asarray(bt[:, 0]), 0])
                    - np.asarray(back[np.asarray(bt[:, 0]), 0]))
     assert np.max(drift) <= 1.01 * float(np.max(np.asarray(ks2)))
     # inactive slot (-1): nothing written
     kq3, ks3, _, _ = paged_insert_quant(
-        kq1, vq1, ks1, vs1, t1, t1, bt, jnp.asarray([1, -1], jnp.int32),
-        kv_bits=kv_bits)
-    np.testing.assert_array_equal(np.asarray(kq3[3:]), np.asarray(kq1[3:]))
-    np.testing.assert_array_equal(np.asarray(ks3[3:]), np.asarray(ks1[3:]))
+        kq1, vq1, ks1, vs1, t1, t1, layer, bt,
+        jnp.asarray([1, -1], jnp.int32), kv_bits=kv_bits)
+    np.testing.assert_array_equal(np.asarray(kq3[1, 3:]),
+                                  np.asarray(kq1[1, 3:]))
+    np.testing.assert_array_equal(np.asarray(ks3[1, 3:]),
+                                  np.asarray(ks1[1, 3:]))
 
 
 def test_paged_insert_quant_same_scale_is_byte_stable():
     """Appending a token no larger than the page's current range must not
     rewrite existing codes (ratio = 1 path is exact, not approximate)."""
-    NB, BS, KV, hd, B = 4, 4, 2, 8, 1
-    kq = jnp.zeros((NB, BS, KV, hd), jnp.int8)
-    ks = jnp.zeros((NB, KV), jnp.float32)
+    L, NB, BS, KV, hd, B = 2, 4, 4, 2, 8, 1
+    kq = jnp.zeros((L, NB, BS, KV * hd), jnp.int8)
+    ks = jnp.zeros((L, NB, KV), jnp.float32)
     bt = jnp.asarray([[0, 1]], jnp.int32)
     big = jnp.full((B, 1, KV, hd), 2.0, jnp.float32)
     small = jnp.full((B, 1, KV, hd), 0.5, jnp.float32)
+    layer = jnp.int32(1)
     kq1, ks1, vq1, vs1 = paged_insert_quant(
-        kq, kq, ks, ks, big, big, bt, jnp.asarray([0], jnp.int32), kv_bits=8)
-    kq2, ks2, _, _ = paged_insert_quant(
-        kq1, vq1, ks1, vs1, small, small, bt, jnp.asarray([1], jnp.int32),
+        kq, kq, ks, ks, big, big, layer, bt, jnp.asarray([0], jnp.int32),
         kv_bits=8)
+    kq2, ks2, _, _ = paged_insert_quant(
+        kq1, vq1, ks1, vs1, small, small, layer, bt,
+        jnp.asarray([1], jnp.int32), kv_bits=8)
     np.testing.assert_array_equal(np.asarray(ks2), np.asarray(ks1))
-    np.testing.assert_array_equal(np.asarray(kq2[0, 0]),
-                                  np.asarray(kq1[0, 0]))
+    np.testing.assert_array_equal(np.asarray(kq2[1, 0, 0]),
+                                  np.asarray(kq1[1, 0, 0]))
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +339,7 @@ def test_runtime_pool_layout_and_bytes(kv_bits, dt, div):
     rt = _runtime(params, cfg, plan)
     hd = cfg.resolved_head_dim
     assert rt.pool["k"].dtype == dt
-    assert rt.pool["k"].shape[-1] == hd // div
+    assert rt.pool["k"].shape[-1] == cfg.n_kv_heads * hd // div
     assert rt.pool["k_scale"].shape == (cfg.n_layers, 24, cfg.n_kv_heads)
     ratio = (paged_cache_bytes(cfg, plan.replace(kv_bits=0), 24, 8)
              / paged_cache_bytes(cfg, plan, 24, 8))

@@ -124,25 +124,34 @@ def test_backpressure_queue_drains_fcfs():
 # ---------------------------------------------------------------------------
 
 def test_paged_attention_ref_vs_fallback():
-    from repro.kernels import ops
-    B, H, KV, hd, NB, BS, MAXB = 3, 4, 2, 16, 10, 4, 5
+    """Kernel, oracle and model fallback all read layer `layer` of the
+    layer-stacked, lane-dense pool and agree with the oracle on that
+    layer's pages."""
+    from repro.kernels import ops, ref
+    L, B, H, KV, hd, NB, BS, MAXB = 3, 3, 4, 2, 16, 10, 4, 5
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (NB, BS, KV, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (NB, BS, KV, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, NB, BS, KV * hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, NB, BS, KV * hd), jnp.float32)
     bt = jnp.asarray(np.random.RandomState(0).randint(0, NB, (B, MAXB)),
                      jnp.int32)
     lengths = jnp.asarray([17, 4, 0], jnp.int32)
     hmap = head_to_kv_map(H, H, KV)
+    layer = jnp.int32(1)
     for window in (0, 6):
         # model fallback (gather + _dense_attention)
-        o_fb = paged_decode_attend(q, kp, vp, bt, lengths, hmap,
+        o_fb = paged_decode_attend(q, kp, vp, layer, bt, lengths, hmap,
                                    window=window, mode="xla")
         # jnp oracle in kernels/ref.py
-        o_ref = ops.paged_attention(q[:, 0], kp, vp, bt, lengths,
+        o_ref = ops.paged_attention(q[:, 0], kp, vp, layer, bt, lengths,
                                     window=window, mode="xla")
+        np.testing.assert_array_equal(
+            np.asarray(o_ref), np.asarray(ref.paged_attention_ref(
+                q[:, 0], kp[1].reshape(NB, BS, KV, hd),
+                vp[1].reshape(NB, BS, KV, hd), bt, lengths,
+                window=window)))
         # pallas kernel, interpret mode
-        o_pl = ops.paged_attention(q[:, 0], kp, vp, bt, lengths,
+        o_pl = ops.paged_attention(q[:, 0], kp, vp, layer, bt, lengths,
                                    window=window, mode="interpret")
         np.testing.assert_allclose(np.asarray(o_fb[:2, 0]),
                                    np.asarray(o_ref[:2]), atol=1e-5)
@@ -151,6 +160,113 @@ def test_paged_attention_ref_vs_fallback():
         # inactive slot: kernel and oracle both produce exact zeros
         assert float(jnp.abs(o_pl[2]).max()) == 0.0
         assert float(jnp.abs(o_ref[2]).max()) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# paged decode step: the pool rides the layer scan, layers index it
+# ---------------------------------------------------------------------------
+
+def _prefilled_pool(cfg, plan, params, tables, lens, seed=7):
+    """Prefill one request per row of `tables` into a fresh pool; returns
+    (pool, last prompt token per slot)."""
+    from repro.models.model import forward
+    from repro.serve.kv_cache import init_paged_cache, write_prefill
+    BS, NB = 4, 12
+    pool = init_paged_cache(cfg, plan, NB, BS)
+    rs = np.random.RandomState(seed)
+    toks = []
+    for s, n in enumerate(lens):
+        t = jnp.asarray(rs.randint(0, cfg.vocab_size, (1, n)), jnp.int32)
+        _, _, cache = forward(params, cfg, plan.replace(kernel_mode=None), t,
+                              make_cache=True)
+        kv = cache["kv"]
+        pool = write_prefill(pool, kv.k[:, 0], kv.v[:, 0], kv.pos[0, 0],
+                             jnp.asarray(tables[s]),
+                             kv_bits=plan.kv_bits)
+        toks.append(int(t[0, -1]))
+    return pool, jnp.asarray(toks, jnp.int32)[:, None]
+
+
+def _paged_decode_run(cfg, plan, params, steps=3):
+    """Two prefilled slots, `steps` greedy paged decode steps: returns
+    (stacked logits, final pool)."""
+    from repro.models.model import decode_step_paged
+    tables = np.asarray([[3, 7, 1, 0], [9, 2, 5, 0]], np.int32)
+    lens = [6, 9]
+    pool, tok = _prefilled_pool(cfg, plan, params, tables, lens)
+    pos = jnp.asarray(lens, jnp.int32)
+    step = jax.jit(lambda p, pool, bt, t, pos: decode_step_paged(
+        p, cfg, plan, pool, bt, t, pos))
+    out = []
+    for _ in range(steps):
+        logits, pool = step(params, pool, jnp.asarray(tables), tok, pos)
+        out.append(np.asarray(logits))
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+        pos = pos + 1
+    return np.stack(out), pool
+
+
+# sha256 of the float32 logits of `_paged_decode_run` on the danube smoke
+# config (seed 0). The XLA gather path gives the same bytes it gave when
+# the decode step still sliced each layer's (…, KV, hd) pool out of the
+# scan's xs: reading the stacked lane-dense pool in place changes none of
+# its arithmetic. The interpret-mode kernels pin their own bytes: their
+# block-diagonal q sums each head's scores over all KV·hd lanes (zeros
+# outside its own KV head), which moves bf16 and int8 pages by a few ulps
+# from the grouped kernel they replace; 4-bit pages kept their bytes.
+PINNED_LOGITS = {
+    (0, "xla"): "e039a799b1f4dd306afe81ef099eea58"
+                "bd251a02827f3c4bdb4f40479ec5552e",
+    (0, "interpret"): "6aa12064c170c04e3ac74b015bc3da5c"
+                      "88db3190bb522cf11499b8c699feb7ae",
+    (8, "xla"): "4db58947c6fbdd1d78d4a27a9627b592"
+                "0978e2ddec4a33a0c22af4cea2848a72",
+    (8, "interpret"): "40a473de426b69657b9d5a0d9269c6a0"
+                      "f486c3aea289f80e943ad5baad42947c",
+    (4, "xla"): "0d89ab893795e9f757e89e2315e07779"
+                "1ec096a479ca55d32d957124bf3a7f73",
+    (4, "interpret"): "dfe5522ee5bb656ee1a238dad88efc0d"
+                      "906b46517614643b7e588d2a3ed9244a",
+}
+
+
+@pytest.mark.parametrize("kv_bits,mode", sorted(PINNED_LOGITS))
+def test_paged_decode_logits_pinned(kv_bits, mode):
+    import hashlib
+    cfg = get_smoke_config("h2o-danube-1.8b").replace(
+        compute_dtype="float32")
+    plan = BuildPlan(remat=False, cache_dtype=jnp.float32, kv_bits=kv_bits,
+                     kernel_mode=mode)
+    params = init_params(jax.random.PRNGKey(0), cfg, plan)
+    logits, _ = _paged_decode_run(cfg, plan, params)
+    assert logits.dtype == np.float32 and logits.shape == (3, 2, 256)
+    digest = hashlib.sha256(logits.tobytes()).hexdigest()
+    assert digest == PINNED_LOGITS[(kv_bits, mode)]
+
+
+@pytest.mark.parametrize("kv_bits", [0, 8])
+def test_decode_step_paged_segmented_equals_plain(kv_bits):
+    """A SegmentedLayers tree (one scan per segment) gives each segment's
+    layers their global indices into the pool: logits and every layer of
+    the pool equal the plain single-scan tree's."""
+    from repro.core.apply import SegmentedLayers
+    cfg = get_smoke_config("h2o-danube-1.8b").replace(
+        compute_dtype="float32", n_layers=4)
+    plan = BuildPlan(remat=False, cache_dtype=jnp.float32, kv_bits=kv_bits)
+    params = init_params(KEY, cfg, plan)
+    bounds = [(0, 1), (1, 3), (3, 4)]
+    seg = dict(params, layers=SegmentedLayers(
+        tuple(jax.tree_util.tree_map(lambda a: a[lo:hi], params["layers"])
+              for lo, hi in bounds), tuple(hi - lo for lo, hi in bounds)))
+    want, want_pool = _paged_decode_run(cfg, plan, params, steps=2)
+    got, got_pool = _paged_decode_run(cfg, plan, seg, steps=2)
+    np.testing.assert_array_equal(got, want)
+    for name in want_pool:
+        np.testing.assert_array_equal(np.asarray(got_pool[name]),
+                                      np.asarray(want_pool[name]))
+    # every layer of the pool was written by its own layer
+    assert all(np.any(np.asarray(want_pool["k"][l]))
+               for l in range(cfg.n_layers))
 
 
 # ---------------------------------------------------------------------------

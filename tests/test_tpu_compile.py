@@ -6,6 +6,9 @@ SMEM overflow).
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library."""
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -61,20 +64,126 @@ def test_quant_matmul_compiles_for_v5e(one_chip, leaf, cpb, M):
 
 @pytest.mark.parametrize("kv_bits", [0, 8, 4])
 def test_paged_attention_compiles_for_v5e(one_chip, kv_bits):
-    B, H, KV, hd, BS, NB, MAXB = 8, 32, 8, 80, 16, 2048, 64
+    """Both kernels read the layer-stacked, lane-dense pool at a layer
+    index."""
+    L, B, H, KV, hd, BS, NB, MAXB = 2, 8, 32, 8, 80, 16, 2048, 64
     q = ((B, H, hd), jnp.bfloat16)
+    layer = ((), jnp.int32)
     tables = (((B, MAXB), jnp.int32), ((B,), jnp.int32))
     if not kv_bits:
-        page = ((NB, BS, KV, hd), jnp.bfloat16)
-        _compile(lambda q, k, v, bt, ln: paged_attention_pallas(
-            q, k, v, bt, ln, window=4096), one_chip, q, page, page, *tables)
+        page = ((L, NB, BS, KV * hd), jnp.bfloat16)
+        _compile(lambda q, k, v, l, bt, ln: paged_attention_pallas(
+            q, k, v, l, bt, ln, window=4096), one_chip, q, page, page, layer,
+            *tables)
         return
     cpb = 1 if kv_bits == 8 else 2
-    page = ((NB, BS, KV, hd // cpb), jnp.int8 if kv_bits == 8 else jnp.uint8)
-    scale = ((NB, KV), jnp.float32)
-    _compile(lambda q, k, v, ks, vs, bt, ln: paged_attention_quant_pallas(
-        q, k, v, ks, vs, bt, ln, window=4096, kv_bits=kv_bits), one_chip,
-        q, page, page, scale, scale, *tables)
+    page = ((L, NB, BS, KV * hd // cpb),
+            jnp.int8 if kv_bits == 8 else jnp.uint8)
+    scale = ((L, NB, KV), jnp.float32)
+    _compile(lambda q, k, v, ks, vs, l, bt, ln: paged_attention_quant_pallas(
+        q, k, v, ks, vs, l, bt, ln, window=4096, kv_bits=kv_bits), one_chip,
+        q, page, page, scale, scale, layer, *tables)
+
+
+# ops that move a whole array: none may produce a layer's pool or more
+MOVES = ("copy", "copy-start", "slice", "dynamic-slice",
+         "dynamic-update-slice")
+# `%name = <shape or (tuple of shapes)> opcode(`; a copy-start's tuple
+# leads with the array it copies into
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = (\([^)]*\)|\S+) "
+                     r"([\w-]+)\(")
+_SHAPE = re.compile(r"(\w+)\[([\d,]*)\]")
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+          "u32": 4, "f32": 4}
+
+
+def _pool_moves(hlo: str, layer_bytes: int):
+    """Instructions, fused or not, of a MOVES opcode whose result holds
+    at least `layer_bytes`."""
+    out = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None or m.group(3) not in MOVES:
+            continue
+        dtype, dims = _SHAPE.search(m.group(2)).groups()
+        dims = [int(d) for d in dims.split(",") if d]
+        if math.prod(dims) * _BYTES.get(dtype, 4) >= layer_bytes:
+            out.append(f"{m.group(1)} {m.group(3)} {dtype}{dims}")
+    return out
+
+
+def test_pool_moves_reads_fused_and_async_ops():
+    """The census sees a copy-start's tuple, a fused computation's root and
+    plain ops, and only at or above the size asked for."""
+    hlo = "\n".join([
+        "  %copy-start.2 = (s8[2,64,8]{2,1,0}, s8[2,64,8]{2,1,0}, u32[])"
+        " copy-start(%p)",
+        "  ROOT %dynamic_slice.9 = bf16[1,64,16]{2,1,0} dynamic-slice(%a, %b)",
+        "  %copy.3 = f32[4]{0} copy(%c)",
+        "  %fusion.1 = bf16[2,64,16]{2,1,0} fusion(%d), kind=kLoop",
+    ])
+    assert _pool_moves(hlo, 1024) == ["copy-start.2 copy-start s8[2, 64, 8]",
+                                      "dynamic_slice.9 dynamic-slice "
+                                      "bf16[1, 64, 16]"]
+    assert _pool_moves(hlo, 4096) == []
+
+
+def _danube_pool_program(sharding, kv_bits, program):
+    """The serve runtime's decode step or prefill write program at danube
+    widths (2 layers, 64 slots, 128 pages a slot, the cell's 4096 pages of
+    16), with the pool donated, compiled for the described chip. Returns
+    (compiled HLO, one layer's pool bytes)."""
+    from repro.configs import get_config
+    from repro.models import BuildPlan
+    from repro.models.model import decode_step_paged, init_params
+    from repro.serve.kv_cache import init_paged_cache, write_prefill
+    cfg = get_config("h2o-danube-1.8b").replace(n_layers=2, head_dim=80)
+    plan = BuildPlan(remat=False, kv_bits=kv_bits, kernel_mode="pallas")
+    B, MAXB, NB, BS, S = 64, 128, 4096, 16, 1024
+    pool = jax.eval_shape(lambda: init_paged_cache(cfg, plan, NB, BS))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool_in = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), pool)
+    if program == "decode":
+        params = jax.eval_shape(
+            lambda: init_params(jax.random.PRNGKey(0), cfg, plan))
+        fn = jax.jit(lambda p, pool, bt, t, pos: decode_step_paged(
+            p, cfg, plan, pool, bt, t, pos), donate_argnums=(1,))
+        args = (jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                       params),
+                pool_in, sds((B, MAXB), jnp.int32), sds((B, 1), jnp.int32),
+                sds((B,), jnp.int32))
+    else:
+        kv_rows = sds((cfg.n_layers, S, cfg.n_kv_heads, 80), jnp.bfloat16)
+        fn = jax.jit(lambda pool, k, v, pos, tbl: write_prefill(
+            pool, k, v, pos, tbl, kv_bits=kv_bits), donate_argnums=(0,))
+        args = (pool_in, kv_rows, kv_rows, sds((S,), jnp.int32),
+                sds((MAXB,), jnp.int32))
+    hlo = fn.lower(*args).compile().as_text()
+    k = pool["k"]
+    return hlo, math.prod(k.shape[1:]) * k.dtype.itemsize
+
+
+@pytest.mark.parametrize("program", ["decode", "write"])
+@pytest.mark.parametrize("kv_bits", [0, 8], ids=["bf16", "int8"])
+def test_pool_is_read_and_written_in_place_for_v5e(one_chip, kv_bits,
+                                                   program):
+    """No copy, slice or update-slice of a layer's pool or more anywhere
+    in the decode step or the prefill write: the pool rides the layer
+    scan's carry, the kernels read it by layer index, and its lane-dense
+    rows keep it in the layout they read. The decode step still holds its
+    kernel by name."""
+    hlo, layer_bytes = _danube_pool_program(one_chip, kv_bits, program)
+    assert _pool_moves(hlo, layer_bytes) == []
+    if program == "decode":
+        calls = {line.split("=", 1)[0].split()[-1].lstrip("%")
+                 .rsplit(".", 1)[0]
+                 for line in hlo.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line}
+        assert ("paged_attention_quant" if kv_bits
+                else "paged_attention") in calls
 
 
 def test_flash_attention_keeps_its_name_for_v5e(one_chip):
